@@ -178,6 +178,8 @@ def main() -> None:
             prev = os.environ.get("XLA_FLAGS", "")
             os.environ["XLA_FLAGS"] = f"{prev} {flag}".strip()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from .common import CsvEmitter
     emit = CsvEmitter()
     emit.header()

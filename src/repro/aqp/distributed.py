@@ -18,7 +18,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core import estimators
@@ -41,8 +40,8 @@ def _group_stats_fn(mesh, m: int):
         g = jnp.maximum(gid_l, 0)
         onehot = jax.nn.one_hot(g, m, dtype=jnp.float32) * valid[:, None]
         cnt = jnp.sum(onehot, axis=0)
-        s1 = onehot.T @ x_l
-        s2 = onehot.T @ (x_l * x_l)
+        s1 = jnp.matmul(onehot.T, x_l, precision="highest")
+        s2 = jnp.matmul(onehot.T, x_l * x_l, precision="highest")
         big = jnp.float32(3e38)
         mn = jnp.min(jnp.where(onehot.T > 0, x_l[None, :], big), axis=1)
         mx = jnp.max(jnp.where(onehot.T > 0, x_l[None, :], -big), axis=1)
@@ -53,7 +52,7 @@ def _group_stats_fn(mesh, m: int):
         mx = jax.lax.pmax(mx, "data")
         return cnt, s1, s2, mn, mx
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh, in_specs=(P("data"), P("data")),
         out_specs=(P(), P(), P(), P(), P())))
 
@@ -94,10 +93,11 @@ def _bootstrap_fn(mesh, m: int, B: int):
         # replicate 0 = the plain sample (weights all 1).
         w_all = jnp.concatenate([jnp.ones((n_l, 1), jnp.float32), w], axis=1)
         # M[g, b, p] = sum_rows onehot[row,g] * w_all[row,b] * feats[row,p]
-        M = jnp.einsum("ng,nb,np->gbp", onehot, w_all, feats)
+        M = jnp.einsum("ng,nb,np->gbp", onehot, w_all, feats,
+                       precision="highest")
         return jax.lax.psum(M, "data")
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("data"), P("data"), P(), P(), P()),
         out_specs=P()))

@@ -131,7 +131,7 @@ def replicates(
     if est.moments_finish is not None:
         v = x[:, 0] if x.ndim == 2 else x
         feats = jnp.stack([jnp.ones_like(v), v, v * v], axis=1)  # (n, 3)
-        M = w @ feats                                            # (B, 3)
+        M = jnp.matmul(w, feats, precision="highest")            # (B, 3)
         return est.moments_finish(M)
     aux = est.prepare(x)
     return jax.vmap(lambda wb: est.apply(aux, wb))(w)
@@ -240,7 +240,8 @@ def lane_moment_sums(v, mf, seeds, B, *, use_kernel=False, interpret=None,
             W = prng.poisson1_weights_at(
                 seeds_l[:, None, None].astype(jnp.uint32),
                 rows[:, None], cols[None, :])                  # (m, w, B)
-            return jnp.einsum("mnb,mnp->mbp", W, feats_l)
+            return jnp.einsum("mnb,mnp->mbp", W, feats_l,
+                              precision="highest")
 
         if lane_active is None:
             M = jax.lax.map(lambda a: lane_M(*a), (feats, seeds))
@@ -319,7 +320,8 @@ def windowed_lane_moment_sums(vals, lo, hi, seeds, B, widths, *,
                     seeds_c[:, :, None, None].astype(jnp.uint32),
                     pos[..., None].astype(jnp.uint32),
                     cols[None, None, None, :])                 # (c, m, w, B)
-                return (jnp.einsum("cmnb,cmnp->cmbp", W, feats),
+                return (jnp.einsum("cmnb,cmnp->cmbp", W, feats,
+                                   precision="highest"),
                         jnp.sum(feats, axis=2))
             return branch
 

@@ -8,6 +8,7 @@ host L2Miss loop (core/l2miss.py) calls them per iteration.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import NamedTuple, Tuple
 
 import jax
@@ -55,10 +56,14 @@ def fit_wls(
     # Ridge-stabilized normal equations (k can be < m+1 early on; the ridge
     # keeps the solve well-posed and the init phase guarantees k >= m+1
     # before predictions are used).
-    G = A.T @ A + 1e-8 * jnp.eye(m + 1, dtype=jnp.float32)
-    beta = jnp.linalg.solve(G, A.T @ y)
+    # Full-f32 products: the log-size columns are nearly collinear with the
+    # intercept, and a TPU's default matmul precision (bf16 operands) turns
+    # the normal equations into noise.
+    mm = partial(jnp.matmul, precision="highest")
+    G = mm(A.T, A) + 1e-8 * jnp.eye(m + 1, dtype=jnp.float32)
+    beta = jnp.linalg.solve(G, mm(A.T, y))
     # Weighted r^2.
-    resid = (N @ beta - profile_loge) * sw
+    resid = (mm(N, beta) - profile_loge) * sw
     mean_y = jnp.sum(w * profile_loge) / jnp.maximum(jnp.sum(w), 1e-12)
     ss_res = jnp.sum(resid**2)
     ss_tot = jnp.sum(w * (profile_loge - mean_y) ** 2)

@@ -929,7 +929,11 @@ def _sharded_step_body(
     # full (S, m, n_cap+1) stack locally -- no pmin collective; the psum
     # on the moment sums is the single barrier a tick crosses.
     allowed = jnp.min(jax.vmap(seg_headroom)(spec.alloc), axis=0)
-    n_vec = jnp.minimum(n_vec, allowed)
+    # ``allowed`` is GROWTH.  An init window stacks past the watermark, so
+    # the window itself is the growth; a prediction window is the prefix
+    # [0, n), which grows the watermark by n - filled.
+    n_vec = jnp.minimum(n_vec, jnp.where(init_phase[:, None], allowed,
+                                         s.filled + allowed))
     n_vec = jnp.where(active[:, None], n_vec, s.n_cur)
     win_lo = jnp.where(init_phase[:, None],
                        jnp.minimum(s.filled, spec.cap_groups[None, :] - n_vec),
@@ -1135,7 +1139,6 @@ _SHARDED_STEP_CACHE_MAX = 16
 @functools.lru_cache(maxsize=_SHARDED_STEP_CACHE_MAX)
 def _make_sharded_step(mesh, num_ticks, statics_items):
     statics = dict(statics_items)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as PS
 
     spec = dict(statics, axis_name="data")
@@ -1158,10 +1161,10 @@ def _make_sharded_step(mesh, num_ticks, statics_items):
             return one(state)
         return jax.lax.fori_loop(0, num_ticks, lambda _, st: one(st), state)
 
-    sm = shard_map(
+    sm = jax.shard_map(
         body, mesh=mesh,
         in_specs=(PS("data", None), st_specs, pr_specs, sp_specs),
-        out_specs=st_specs, check_rep=False)
+        out_specs=st_specs, check_vma=False)
     return jax.jit(sm)
 
 

@@ -12,6 +12,12 @@ def kernel_backend_available() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def interpret_default() -> bool:
+    """Kernel mode when a caller passes ``interpret=None``: compiled through
+    Mosaic on a TPU, the Pallas interpreter everywhere else."""
+    return not kernel_backend_available()
+
+
 def resolve_use_kernel(mode: "bool | str") -> bool:
     """Resolve a tri-state kernel switch to a concrete bool.
 

@@ -20,8 +20,8 @@
                               same (e, theta_hat) contract, bootstrap
                               replicates computed by the Pallas kernel.
 
-On CPU containers the kernel runs in interpret mode (selected automatically);
-on TPU it compiles to Mosaic.
+With ``interpret=None`` the kernel compiles to Mosaic on a TPU and runs in
+interpret mode elsewhere (``kernels.interpret_default``).
 """
 from __future__ import annotations
 
@@ -32,11 +32,8 @@ import jax.numpy as jnp
 
 from ...core.bootstrap import _joint_metric
 from ...core.estimators import get as get_estimator
+from .. import interpret_default
 from . import kernel as K
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _round_up(x: int, mult: int) -> int:
@@ -68,7 +65,7 @@ def bootstrap_moments(
 ) -> jax.Array:
     """(B, 5) replicate moment sums [sum w, sum wx, ..., sum wx^4]."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     n_pad = _round_up(x.shape[0], tn)
     B_pad = _round_up(B, tb)
     feats = build_feats(x, mask, n_pad)
@@ -110,7 +107,7 @@ def bootstrap_moments_masked(
     those groups.  Active groups are bit-equal with any flag pattern.
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     lead = x.shape[:-1]
     n = x.shape[-1]
     n_pad = _round_up(n, tn)
@@ -165,7 +162,8 @@ def estimate_error_moments(
     # Guard dead replicates (sum w == 0): substitute the plain sample.
     mf = mask.astype(jnp.float32)
     feats = jnp.stack([mf, mf * v, mf * v * v], axis=-1)       # (m, n, 3)
-    M_plain = jnp.einsum("mn,mnp->mp", mf, feats)              # (m, 3)
+    M_plain = jnp.einsum("mn,mnp->mp", mf, feats,
+                         precision="highest")                  # (m, 3)
     dead = M[:, :, 0:1] <= 0
     M3 = jnp.where(dead, M_plain[:, None, :], M[:, :, :3])
     reps = est.moments_finish(M3)                              # (m, B, 1)

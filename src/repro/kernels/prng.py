@@ -44,8 +44,13 @@ def hash3(seed, row, col):
 
 
 def uniform01(bits):
-    """uint32 bits -> f32 uniform in [0, 1) using the top 24 bits."""
-    return (bits >> 8).astype(jnp.float32) * (2.0**-24)
+    """uint32 bits -> f32 uniform in [0, 1) using the top 24 bits.
+
+    The cast goes through int32 because Mosaic has no uint32 -> f32 cast;
+    ``bits >> 8`` is below 2**24, so both casts are exact and the result is
+    bit-identical to a direct conversion.
+    """
+    return (bits >> 8).astype(jnp.int32).astype(jnp.float32) * (2.0**-24)
 
 
 # Poisson(1) CDF ladder -- MUST stay identical to

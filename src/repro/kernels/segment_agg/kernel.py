@@ -53,7 +53,7 @@ def _kernel(feats_ref, gid_ref, x_ref, mask_ref,
     # MXU: (P, tn) x (m_pad, tn) contracting tn -> (P, m_pad).
     mom_ref[...] += jax.lax.dot_general(
         feats_ref[...], onehot.astype(jnp.float32),
-        dimension_numbers=(((1,), (1,)), ((), ())),
+        dimension_numbers=(((1,), (1,)), ((), ())), precision="highest",
         preferred_element_type=jnp.float32)
     # VPU: masked min/max per group, broadcast across the 8 sublane rows.
     xb = jnp.broadcast_to(x, (m_pad, tn))
@@ -101,7 +101,7 @@ def _boot_kernel(feats_ref, gid_ref, slot_ref, seed_ref, out_ref,
     mom = [
         jax.lax.dot_general(
             onehot, w * feats_ref[p:p + 1, :],
-            dimension_numbers=(((1,), (1,)), ((), ())),
+            dimension_numbers=(((1,), (1,)), ((), ())), precision="highest",
             preferred_element_type=jnp.float32)
         for p in range(3)
     ]

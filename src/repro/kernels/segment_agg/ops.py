@@ -6,6 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .. import interpret_default
 from . import kernel as K
 
 
@@ -31,7 +32,7 @@ def segment_aggregate(
     the pass-local range, so every pass runs the identical 128-wide kernel.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     if m > 128:
         gid = gid.astype(jnp.int32)
         mf = mask.astype(jnp.float32)
@@ -89,7 +90,7 @@ def segment_bootstrap_moments(
     watermark of the block), not ``m x n_cap``.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     n = gid.shape[0]
     n_pad = _round_up(max(n, tn), tn)
     pad = n_pad - n

@@ -80,6 +80,7 @@ from ..core import estimators
 from ..core import sanitize
 from ..core.sampling import (GroupedData, ShardLayout, counter_slot_table,
                              stratified_slot_tables)
+from ..kernels import resolve_use_kernel
 from .slo import (PILOT_B_FLOOR, AdmissionController, FairQueue,
                   predict_n0)
 
@@ -340,7 +341,8 @@ class LanePool:
                  n_min: int = 1000, n_max: int = 2000, max_iters: int = 24,
                  n_cap: int = 1 << 16, l: Optional[int] = None,
                  metric: str = "l2", growth_cap: float = 8.0,
-                 ext_cap: Optional[int] = None, use_kernel: bool = False,
+                 ext_cap: Optional[int] = None,
+                 use_kernel: "bool | str" = "auto",
                  gate_gather: bool = True, seed: int = 0,
                  sample_key: Optional[Array] = None,
                  ticks_per_sync: int = 1, tiers: "int | str" = "auto",
@@ -350,6 +352,7 @@ class LanePool:
                  migrate: bool = False, max_degrade: float = 8.0):
         self.data = data
         self.lanes = int(lanes)
+        use_kernel = resolve_use_kernel(use_kernel)
         if tiers == "auto":
             tiers = 2 if self.lanes >= 2 and self.lanes % 2 == 0 else 1
         self.tiers = int(tiers)
@@ -1014,6 +1017,12 @@ class LanePool:
                 return
 
     @property
+    def values(self) -> Array:
+        """The resident table as the tiers read it: row-sharded over the
+        mesh (and padded to the shard layout) when ``data_shards > 1``."""
+        return self._values
+
+    @property
     def ticks_per_sync(self) -> int:
         return self._ticks_per_sync
 
@@ -1036,6 +1045,33 @@ class LanePool:
         if self._mesh is not None:
             size += sharded_step_cache_size()
         return int(size)
+
+    def _tier_program(self, tier: _Tier):
+        """``(program, args, kwargs)`` of one tier dispatch."""
+        if self._mesh is not None:
+            step = self._step_cache.get(self.ticks_per_sync)
+            if step is None:
+                step = make_sharded_step(
+                    self._mesh, num_ticks=self.ticks_per_sync, **self._spec)
+                self._step_cache[self.ticks_per_sync] = step
+            return (step, (self._values, tier.state, tier.params,
+                           self._shard_spec), {})
+        args = (self._values, self._offsets, tier.state, tier.params)
+        if self._layout is not None:
+            # Single-device run of the SAME shard layout (mesh=False): the
+            # sequential segment fold the mesh psum reproduces.  seg_window
+            # passes through exactly as compiled for the mesh spec -- no
+            # ext_cap re-resolution in between.
+            args += (self._shard_spec,)
+        return fused_step, args, dict(num_ticks=self.ticks_per_sync,
+                                      **self._spec)
+
+    def lowered_tick(self) -> "jax.stages.Lowered":
+        """The program a tier dispatch runs, lowered at the pool's live
+        shapes and cadence -- for inspecting what a tick compiles to
+        (``.as_text()``, ``.compile().memory_analysis()``)."""
+        step, args, kw = self._tier_program(self._tiers[0])
+        return step.lower(*args, **kw)
 
     def tick(self) -> int:
         """One scheduling round: refill, run ``ticks_per_sync`` loop ticks
@@ -1074,28 +1110,8 @@ class LanePool:
                 self._warmed_tiers.add(ti)
                 self._note_new_program_config()
             round_rung = max(round_rung, tier.width)
-            if self._mesh is not None:
-                step = self._step_cache.get(self.ticks_per_sync)
-                if step is None:
-                    step = make_sharded_step(
-                        self._mesh, num_ticks=self.ticks_per_sync,
-                        **self._spec)
-                    self._step_cache[self.ticks_per_sync] = step
-                tier.state = step(self._values, tier.state, tier.params,
-                                  self._shard_spec)
-            elif self._layout is not None:
-                # Single-device run of the SAME shard layout (mesh=False):
-                # the sequential segment fold the mesh psum reproduces.
-                # seg_window passes through exactly as compiled for the
-                # mesh spec -- no ext_cap re-resolution in between.
-                tier.state = fused_step(
-                    self._values, self._offsets, tier.state, tier.params,
-                    self._shard_spec, num_ticks=self.ticks_per_sync,
-                    **self._spec)
-            else:
-                tier.state = fused_step(
-                    self._values, self._offsets, tier.state, tier.params,
-                    num_ticks=self.ticks_per_sync, **self._spec)
+            step, args, kw = self._tier_program(tier)
+            tier.state = step(*args, **kw)
             self.dispatches += 1
             self.lane_ticks_busy += busy * self.ticks_per_sync
             self._active_frac_sum += busy / self.tier_lanes

@@ -223,6 +223,11 @@ class AQPSession:
         return self.store.rows_touched + self._fused_rows
 
     @property
+    def pool(self) -> Optional[LanePool]:
+        """The live lane pool (None until the first pooled request)."""
+        return self._pool
+
+    @property
     def in_flight(self) -> int:
         """Requests submitted but not yet finished (queued or running)."""
         return len(self._inflight)

@@ -70,7 +70,6 @@ def make_pod_gradient_sync(mesh, *, enabled: bool = True):
     if not enabled or "pod" not in mesh.axis_names:
         return lambda g, r: (g, r)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def sync_leaf(g, r):
@@ -79,8 +78,8 @@ def make_pod_gradient_sync(mesh, *, enabled: bool = True):
             npods = jax.lax.psum(jnp.ones(()), "pod")
             return s / npods, nr
         spec = P()  # gradients replicated over pod (DP) before sync
-        return shard_map(inner, mesh=mesh, in_specs=(spec, spec),
-                         out_specs=(spec, spec))(g, r)
+        return jax.shard_map(inner, mesh=mesh, in_specs=(spec, spec),
+                             out_specs=(spec, spec))(g, r)
 
     def grad_sync(grads, resids):
         flat_g, tdef = jax.tree.flatten(grads)

@@ -34,6 +34,21 @@ def test_prng_uniformity_and_determinism():
     assert not np.allclose(u, u3)
 
 
+def test_uniform01_bits_equal_direct_uint32_cast():
+    """The int32 detour Mosaic needs changes no bit of the uniforms."""
+    rng = np.random.default_rng(0)
+    edges = np.asarray([0, 1, 255, 256, 2**24, 2**31 - 1, 2**31, 2**32 - 1],
+                       np.uint64)
+    bits = jnp.asarray(np.concatenate(
+        [edges, rng.integers(0, 2**32, 1 << 16, dtype=np.uint64)]
+    ).astype(np.uint32))
+    direct = (bits >> 8).astype(jnp.float32) * (2.0**-24)
+    got = prng.uniform01(bits)
+    assert np.asarray(got).tobytes() == np.asarray(direct).tobytes()
+    assert float(got[-1 - (1 << 16)]) == 1.0 - 2.0**-24    # 2^32 - 1
+    assert float(got[0]) == 0.0
+
+
 def test_prng_poisson_ladder_matches_core():
     from repro.core.bootstrap import _POISSON1_CDF
 

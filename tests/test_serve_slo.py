@@ -167,7 +167,9 @@ def test_queued_ticket_shed_when_deadline_passes(data):
     q1 = pool.submit(Query("avg", epsilon=0.02))
     pool.tick()
     assert pool.busy_lanes == 2
-    ddl = time.perf_counter() + 1e-3
+    # The deadline must still be ahead when submit() checks it: a submit
+    # takes ~0.5-1 ms on a CPU host, more under a loaded test run.
+    ddl = time.perf_counter() + 0.05
     q2 = pool.submit(Query("avg", epsilon=0.05), deadline_at=ddl)
     assert pool.queue_depth == 1      # lanes busy: it queues
     while time.perf_counter() < ddl:

@@ -195,6 +195,20 @@ def test_solo_sharded_closed_loop_converges(data):
         assert np.all(n >= 1) and np.all(n <= SPEC["n_cap"])
 
 
+def test_sharded_lane_grows_past_one_window(data):
+    """The growth clamp bounds one tick's EXTENSION, not the sample size: a
+    prediction-phase lane whose n* lies several windows out keeps growing
+    tick after tick until it converges."""
+    n_cap = 4096
+    seg_window = resolve_seg_window(n_cap, SPEC["n_max"], 4)
+    out = _solo_sharded(data, 0.07, jax.random.PRNGKey(2),
+                        jax.random.PRNGKey(9), 4, n_cap=n_cap, max_iters=16)
+    assert bool(out.success)
+    # Each group spans two of the four shards, so one tick grows it by
+    # about 2 * seg_window logical slots.
+    assert np.max(np.ravel(out.n)) > 4 * seg_window
+
+
 def _drain(pool, specs, keys):
     from repro.aqp.query import Query
     qids = [pool.submit(Query(func=f, epsilon=e), key=keys[i])
