@@ -1,4 +1,4 @@
-"""Benchmark driver: one section per paper table/figure + kernels + roofline.
+"""Benchmark sections: one per paper table/figure, plus kernels and serving.
 
     PYTHONPATH=src python -m benchmarks.run            # standard pass
     PYTHONPATH=src python -m benchmarks.run --full     # paper-scale sizes
@@ -62,11 +62,6 @@ def _run_kern(emit, args):
     bench_kernels.run(emit, full=args.full)
 
 
-def _run_roofline(emit, args):
-    from . import bench_roofline
-    bench_roofline.run(emit)
-
-
 def _run_store(emit, args):
     from . import bench_sample_store
     bench_sample_store.run(emit, full=args.full)
@@ -113,7 +108,6 @@ SECTIONS = {
     "fig3": _run_fig3,
     "fig4": _run_fig4,
     "kern": _run_kern,
-    "roofline": _run_roofline,
     "store": _run_store,
     "fused": _run_fused,
     "serve": _run_serve,

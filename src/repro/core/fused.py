@@ -410,11 +410,26 @@ def init_lane_state(
     )
 
 
+def scoped(name: str):
+    """Decorator: trace the function under ``jax.named_scope(name)``, a
+    fresh scope per call (one shared ``named_scope`` object keeps the scope
+    it replaced on itself, which reentrant or threaded tracing would mix
+    up).  The name reaches the compiled ops' ``op_name`` metadata only."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
+
+
 def lane_active(state: LaneState, max_iters: int) -> Array:
     """(q,) lanes still iterating: not converged, not failed, ticks left."""
     return ~state.done & ~state.failed & (state.k < max_iters)
 
 
+@scoped("miss.fit")
 def _fit_predict(s: LaneState, p: LaneParams, *, tau: float,
                  growth_cap: float, max_iters: int, l: int):
     """FIT + PREDICT for every lane (shared by the solo and sharded bodies).
@@ -474,6 +489,7 @@ def _fit_predict(s: LaneState, p: LaneParams, *, tau: float,
         use_warm, s.k, p.warm_n0, p.warm_beta)
 
 
+@scoped("miss.epilogue")
 def _lane_epilogue(s: LaneState, p: LaneParams, *, max_iters, active,
                    init_phase, new_keys, e_b, theta_b, n_eff, filled, buf,
                    beta, r2, failed_fit) -> LaneState:
@@ -529,29 +545,31 @@ def _segment_tick(values, s, p, *, active, win_lo, win_hi, seeds, est,
     lo, hi = win_lo[:, 0], win_hi[:, 0]
 
     # ---- one packed gather over the extension windows [filled, win_hi) ----
-    ext_w = jnp.maximum(hi - filled0, 0)       # inactive: hi <= filled -> 0
-    gather_cap = min(seg_cap, q * ext_cap)
-    g_rungs = _window_ladder(gather_cap,
-                             min(sampling.bucket_cap(n_max), gather_cap))
-    g_total = jnp.sum(ext_w)
-    g_idx = jnp.sum(g_total > jnp.asarray(g_rungs[:-1], jnp.int32))
-    g_starts = jnp.cumsum(ext_w) - ext_w                       # (q,)
+    with jax.named_scope("miss.gather"):
+        ext_w = jnp.maximum(hi - filled0, 0)   # inactive: hi <= filled -> 0
+        gather_cap = min(seg_cap, q * ext_cap)
+        g_rungs = _window_ladder(gather_cap,
+                                 min(sampling.bucket_cap(n_max), gather_cap))
+        g_total = jnp.sum(ext_w)
+        g_idx = jnp.sum(g_total > jnp.asarray(g_rungs[:-1], jnp.int32))
+        g_starts = jnp.cumsum(ext_w) - ext_w                   # (q,)
 
-    def mk_gather(L):
-        def branch(buf_b):
-            j = jnp.arange(L, dtype=jnp.int32)
-            lane_j = jnp.clip(
-                jnp.searchsorted(g_starts, j, side="right") - 1, 0, q - 1)
-            slot_j = filled0[lane_j] + (j - g_starts[lane_j])
-            valid = j < g_total
-            gidx = p.slot_idx[lane_j, 0, jnp.minimum(slot_j, n_cap - 1)]
-            rows = values[gidx]                                # (L, c)
-            tgt = jnp.where(valid, slot_j, n_cap)              # OOB -> drop
-            return buf_b.at[lane_j, 0, tgt].set(rows, mode="drop")
-        return branch
+        def mk_gather(L):
+            def branch(buf_b):
+                j = jnp.arange(L, dtype=jnp.int32)
+                lane_j = jnp.clip(
+                    jnp.searchsorted(g_starts, j, side="right") - 1, 0,
+                    q - 1)
+                slot_j = filled0[lane_j] + (j - g_starts[lane_j])
+                valid = j < g_total
+                gidx = p.slot_idx[lane_j, 0, jnp.minimum(slot_j, n_cap - 1)]
+                rows = values[gidx]                            # (L, c)
+                tgt = jnp.where(valid, slot_j, n_cap)          # OOB -> drop
+                return buf_b.at[lane_j, 0, tgt].set(rows, mode="drop")
+            return branch
 
-    buf = jax.lax.switch(g_idx.astype(jnp.int32),
-                         [mk_gather(w) for w in g_rungs], s.buf)
+        buf = jax.lax.switch(g_idx.astype(jnp.int32),
+                             [mk_gather(w) for w in g_rungs], s.buf)
     filled = jnp.maximum(s.filled, win_hi)
 
     # ---- one segment-aggregated ESTIMATE over [win_lo, win_hi) ----
@@ -576,11 +594,12 @@ def _segment_tick(values, s, p, *, active, win_lo, win_hi, seeds, est,
                 use_kernel=use_kernel)
         return branch
 
-    M, Mp = jax.lax.switch(e_idx.astype(jnp.int32),
-                           [mk_est(w) for w in e_rungs], buf)
-    e_b, theta_b = bootstrap.finish_lanes_moments(
-        M[:, None], Mp[:, None], p.scale, p.deltas, est=est,
-        est_fids=p.est_fids, metric=metric)
+    with jax.named_scope("miss.estimate"):
+        M, Mp = jax.lax.switch(e_idx.astype(jnp.int32),
+                               [mk_est(w) for w in e_rungs], buf)
+        e_b, theta_b = bootstrap.finish_lanes_moments(
+            M[:, None], Mp[:, None], p.scale, p.deltas, est=est,
+            est_fids=p.est_fids, metric=metric)
     return buf, filled, e_b, theta_b
 
 
@@ -703,28 +722,28 @@ def _step_body(
         return buf_l.at[jnp.arange(m)[:, None], tgt].set(
             new_rows, mode="drop")
 
-    if gate_gather:
-        # Per-lane lax.cond (a REAL branch under lax.map, not the
-        # execute-both of vmapped control flow): frozen/parked lanes skip
-        # the gather entirely, so a tick's HBM row traffic is bounded by
-        # sum(active) * ext_cap instead of q * ext_cap.  Exact skip:
-        # an inactive lane's window degenerates to the resident prefix
-        # (win_hi <= filled above), so its gather would scatter nothing --
-        # gated and ungated buffers are bit-identical.
-        def _one(args):
-            buf_l, filled_l, hi_l, act_l = args[:4]
-            slot_idx_l = p.slot_idx if shared_slots else args[4]
-            return jax.lax.cond(
-                act_l,
-                lambda _: _lane_gather(buf_l, filled_l, hi_l, slot_idx_l),
-                lambda _: buf_l, 0)
+    with jax.named_scope("miss.gather"):
+        if gate_gather:
+            # Per-lane lax.cond (a REAL branch under lax.map, not the
+            # execute-both of vmapped control flow): frozen/parked lanes
+            # skip the gather entirely, so a tick's HBM row traffic is
+            # bounded by sum(active) * ext_cap instead of q * ext_cap.
+            # Exact skip: an inactive lane's window degenerates to the
+            # resident prefix (win_hi <= filled above), so its gather would
+            # scatter nothing -- gated and ungated buffers are bit-identical.
+            def _one(args):
+                buf_l, filled_l, hi_l, act_l = args[:4]
+                slot_idx_l = p.slot_idx if shared_slots else args[4]
+                return jax.lax.cond(
+                    act_l,
+                    lambda _: _lane_gather(buf_l, filled_l, hi_l, slot_idx_l),
+                    lambda _: buf_l, 0)
 
-        operands = (s.buf, s.filled, win_hi, active)
-        if not shared_slots:
-            operands = operands + (p.slot_idx,)
-        buf = jax.lax.map(_one, operands)
-    else:
-        if shared_slots:
+            operands = (s.buf, s.filled, win_hi, active)
+            if not shared_slots:
+                operands = operands + (p.slot_idx,)
+            buf = jax.lax.map(_one, operands)
+        elif shared_slots:
             buf = jax.lax.map(
                 lambda a: _lane_gather(a[0], a[1], a[2], p.slot_idx),
                 (s.buf, s.filled, win_hi))
@@ -776,9 +795,10 @@ def _step_body(
                     metric=metric))(bw, msk, kest_b, p.scale, p.deltas)
         return branch
 
-    e_b, theta_b = jax.lax.switch(
-        b_idx, [make_branch(w) for w in widths],
-        buf, win_lo, win_hi, seeds, kest)
+    with jax.named_scope("miss.estimate"):
+        e_b, theta_b = jax.lax.switch(
+            b_idx, [make_branch(w) for w in widths],
+            buf, win_lo, win_hi, seeds, kest)
     return _lane_epilogue(
         s, p, max_iters=max_iters, active=active, init_phase=init_phase,
         new_keys=new_keys, e_b=e_b, theta_b=theta_b, n_eff=n_eff,
@@ -995,35 +1015,38 @@ def _sharded_step_body(
 
             return jax.lax.cond(act_l, grow_any, lambda _: buf_l, 0)
 
-        buf_new = jax.lax.map(lane_gather, (buf_seg, lfill, lhi, active))
+        with jax.named_scope("miss.gather"):
+            buf_new = jax.lax.map(lane_gather, (buf_seg, lfill, lhi, active))
         seeds_s = prng.hash3(seeds, seg_id, jnp.uint32(_SALT_SHARD))
-        if use_kernel:
-            # Kernel path: prefix semantics, one shared rung -- the tile
-            # grid is what gates per-lane cost there.
-            needed = jnp.maximum(
-                jnp.max(jnp.where(active[:, None], lhi, 0)), 1)
-            b_idx = jnp.sum(needed > w_arr).astype(jnp.int32)
+        with jax.named_scope("miss.estimate"):
+            if use_kernel:
+                # Kernel path: prefix semantics, one shared rung -- the tile
+                # grid is what gates per-lane cost there.
+                needed = jnp.maximum(
+                    jnp.max(jnp.where(active[:, None], lhi, 0)), 1)
+                b_idx = jnp.sum(needed > w_arr).astype(jnp.int32)
 
-            def make_branch(width):
-                def branch(buf_b, lo_b, hi_b, seeds_b):
-                    bw = jax.lax.slice_in_dim(buf_b, 0, width, axis=2)
-                    pos = jnp.arange(width, dtype=jnp.int32)[None, None, :]
-                    msk = ((pos >= lo_b[:, :, None]) &
-                           (pos < hi_b[:, :, None])).astype(jnp.float32)
-                    return bootstrap.lane_moment_sums(
-                        bw[..., 0].astype(jnp.float32), msk, seeds_b, B,
-                        use_kernel=True, lane_active=active)
-                return branch
+                def make_branch(width):
+                    def branch(buf_b, lo_b, hi_b, seeds_b):
+                        bw = jax.lax.slice_in_dim(buf_b, 0, width, axis=2)
+                        pos = jnp.arange(width, dtype=jnp.int32)[
+                            None, None, :]
+                        msk = ((pos >= lo_b[:, :, None]) &
+                               (pos < hi_b[:, :, None])).astype(jnp.float32)
+                        return bootstrap.lane_moment_sums(
+                            bw[..., 0].astype(jnp.float32), msk, seeds_b, B,
+                            use_kernel=True, lane_active=active)
+                    return branch
 
-            M_s, Mp_s = jax.lax.switch(
-                b_idx, [make_branch(w) for w in seg_widths],
-                buf_new, llo, lhi, seeds_s)
-        else:
-            # jnp path: windowed gather at per-lane rungs -- see
-            # bootstrap.windowed_lane_moment_sums for why both matter.
-            M_s, Mp_s = bootstrap.windowed_lane_moment_sums(
-                buf_new[..., 0], llo, lhi, seeds_s, B, seg_widths,
-                lane_active=active)
+                M_s, Mp_s = jax.lax.switch(
+                    b_idx, [make_branch(w) for w in seg_widths],
+                    buf_new, llo, lhi, seeds_s)
+            else:
+                # jnp path: windowed gather at per-lane rungs -- see
+                # bootstrap.windowed_lane_moment_sums for why both matter.
+                M_s, Mp_s = bootstrap.windowed_lane_moment_sums(
+                    buf_new[..., 0], llo, lhi, seeds_s, B, seg_widths,
+                    lane_active=active)
         return buf_new, M_s, Mp_s
 
     if axis_name is None:
@@ -1046,11 +1069,14 @@ def _sharded_step_body(
         sid = jax.lax.axis_index(axis_name)
         buf, M_s, Mp_s = seg_tick(s.buf, spec.alloc[sid], p.slot_idx[0],
                                   sid.astype(jnp.uint32))
-        M = jax.lax.psum(M_s, axis_name)
-        Mp = jax.lax.psum(Mp_s, axis_name)
+        with jax.named_scope("miss.psum"):
+            M = jax.lax.psum(M_s, axis_name)
+            Mp = jax.lax.psum(Mp_s, axis_name)
 
-    e_b, theta_b = bootstrap.finish_lanes_moments(
-        M, Mp, p.scale, p.deltas, est=est, est_fids=p.est_fids, metric=metric)
+    with jax.named_scope("miss.estimate"):
+        e_b, theta_b = bootstrap.finish_lanes_moments(
+            M, Mp, p.scale, p.deltas, est=est, est_fids=p.est_fids,
+            metric=metric)
     return _lane_epilogue(
         s, p, max_iters=max_iters, active=active, init_phase=init_phase,
         new_keys=new_keys, e_b=e_b, theta_b=theta_b, n_eff=n_eff,

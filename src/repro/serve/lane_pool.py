@@ -74,7 +74,7 @@ from ..core.fused import (LaneParams, LaneState, ShardSpec, bucket_ladder,
                           lane_boot_seed, make_group_lane_params,
                           make_lane_params, make_shard_spec,
                           make_sharded_lane_params, make_sharded_step,
-                          resolve_ext_cap, resolve_seg_window,
+                          resolve_ext_cap, resolve_seg_window, scoped,
                           sharded_step_cache_size)
 from ..core import estimators
 from ..core import sanitize
@@ -83,6 +83,7 @@ from ..core.sampling import (GroupedData, ShardLayout, counter_slot_table,
 from ..kernels import resolve_use_kernel
 from .slo import (PILOT_B_FLOOR, AdmissionController, FairQueue,
                   predict_n0)
+from .tracing import PhaseRecorder
 
 Array = jax.Array
 
@@ -226,6 +227,7 @@ class _Tier:
 
 
 @partial(jax.jit, static_argnames=("n_min",))
+@scoped("miss.splice")
 def _splice(state: LaneState, params: LaneParams, lanes, keys, scale_rows,
             eps, deltas, fids, warm, warm_n0, warm_beta, *, n_min: int):
     """Reset lanes ``lanes`` to tick 0, swapping in their new queries.
@@ -349,9 +351,13 @@ class LanePool:
                  data_shards: int = 1, mesh=None,
                  degrade: bool = False, wfq: bool = False,
                  tenant_weights: Optional[Dict[str, float]] = None,
-                 migrate: bool = False, max_degrade: float = 8.0):
+                 migrate: bool = False, max_degrade: float = 8.0,
+                 recorder: Optional[PhaseRecorder] = None):
         self.data = data
         self.lanes = int(lanes)
+        # Phase spans and counters: the owning session's recorder, so they
+        # survive pool rebuilds; a pool built alone keeps its own.
+        self.recorder = recorder if recorder is not None else PhaseRecorder()
         use_kernel = resolve_use_kernel(use_kernel)
         if tiers == "auto":
             tiers = 2 if self.lanes >= 2 and self.lanes % 2 == 0 else 1
@@ -477,6 +483,8 @@ class LanePool:
         self._goffsets = jnp.asarray(
             [0, int(np.asarray(data.offsets)[-1])], jnp.int32)
         self._gtables: Optional[Array] = None   # stratified tables, per epoch
+        # (state, params) shapes of this pool's blocks, once one is admitted.
+        self._block_shapes = None
         self._pending_sample_key: Optional[Array] = None
         self.sample_epochs = 0    # applied slot-table rotations
         self._scale_rows: Dict[str, np.ndarray] = {}
@@ -619,7 +627,7 @@ class LanePool:
         tk = _Ticket(
             qid=qid, func=query.func, fid=self._family[query.func],
             epsilon=float(query.epsilon), delta=float(query.delta),
-            key=jax.device_get(key), scale_row=scale_row,
+            key=self.recorder.device_get(key), scale_row=scale_row,
             submitted_s=time.perf_counter(),
             priority=int(priority), deadline_at=deadline_at,
             warm_n0=warm_n0, warm_beta=warm_beta,
@@ -713,6 +721,10 @@ class LanePool:
         self._next_qid += 1
         self.submitted += 1
         self.grouped_submitted += 1
+        if self._block_shapes is None:
+            self._block_shapes = jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                (state, params))
         self._blocks[qid] = _Block(
             qid=qid, func=query.func, state=state, params=params,
             submitted_s=time.perf_counter(), admitted_tick=self.ticks,
@@ -863,7 +875,7 @@ class LanePool:
         # One explicit sync for both outputs -- the pilot result is
         # consumed host-side here by design (implicit syncs in the tick
         # path trip the sanitizer's transfer guard).
-        err, theta_host = jax.device_get((e, theta))
+        err, theta_host = self.recorder.device_get((e, theta))
         err = float(err)
         n_pilot = int(min(self._spec["n_min"], self._spec["n_cap"]))
         n = np.minimum(self._group_sizes_host, n_pilot)
@@ -892,7 +904,7 @@ class LanePool:
             if tier.busy == 0:
                 continue
             s = tier.state
-            done, failed, k, filled = jax.device_get(
+            done, failed, k, filled = self.recorder.device_get(
                 (s.done, s.failed, s.k, s.filled))
             tier.filled_host = np.asarray(filled, np.int64)
             finished = [lane for lane, t in enumerate(tier.occupant)
@@ -901,7 +913,7 @@ class LanePool:
                              or k[lane] >= max_iters)]
             if not finished:
                 continue
-            e, n_cur, iters, theta, beta = jax.device_get(
+            e, n_cur, iters, theta, beta = self.recorder.device_get(
                 (s.e, s.n_cur, s.iters, s.theta, s.beta))
             for lane in finished:
                 t = tier.occupant[lane]
@@ -950,10 +962,11 @@ class LanePool:
         finished: List[int] = []
         for qid, blk in self._blocks.items():
             s = blk.state
-            done, failed, k = jax.device_get((s.done, s.failed, s.k))
+            done, failed, k = self.recorder.device_get(
+                (s.done, s.failed, s.k))
             if not bool(np.all(done | failed | (k >= max_iters))):
                 continue
-            e, n_cur, iters, theta, beta, filled = jax.device_get(
+            e, n_cur, iters, theta, beta, filled = self.recorder.device_get(
                 (s.e, s.n_cur, s.iters, s.theta, s.beta, s.filled))
             rows = int(np.asarray(filled).sum())
             self.results[qid] = GroupPoolResponse(
@@ -1066,11 +1079,23 @@ class LanePool:
         return fused_step, args, dict(num_ticks=self.ticks_per_sync,
                                       **self._spec)
 
-    def lowered_tick(self) -> "jax.stages.Lowered":
-        """The program a tier dispatch runs, lowered at the pool's live
-        shapes and cadence -- for inspecting what a tick compiles to
+    def _block_program(self, state: LaneState, params: LaneParams):
+        """``(program, args, kwargs)`` of one grouped block dispatch."""
+        return fused_step, (self._values, self._goffsets, state, params), \
+            dict(num_ticks=self.ticks_per_sync, seg_cap=self._gseg_cap,
+                 **self._spec)
+
+    def lowered_tick(self, grouped: bool = False) -> "jax.stages.Lowered":
+        """The program a tier dispatch runs (``grouped``: a grouped block's
+        dispatch, once the pool has admitted one), lowered at the pool's
+        live shapes and cadence -- for inspecting what a tick compiles to
         (``.as_text()``, ``.compile().memory_analysis()``)."""
-        step, args, kw = self._tier_program(self._tiers[0])
+        if not grouped:
+            step, args, kw = self._tier_program(self._tiers[0])
+        elif self._block_shapes is None:
+            raise ValueError("the pool has admitted no grouped block yet")
+        else:
+            step, args, kw = self._block_program(*self._block_shapes)
         return step.lower(*args, **kw)
 
     def tick(self) -> int:
@@ -1084,60 +1109,69 @@ class LanePool:
         must be an explicit ``jax.device_get`` harvest.  Afterwards the
         recompile sentinel attributes any program-cache growth not
         explained by a config event to ``steady_recompiles``.
+
+        Spans (``self.recorder``): ``tick``, with ``refill`` (queue pick,
+        padded batch, splice dispatch), ``dispatch`` (the asynchronous
+        step enqueues) and ``harvest`` (retirement and migration) inside.
         """
-        with sanitize.guarded():
-            out = self._tick_inner()
-        size = self._program_cache_size()
-        if self._steady_cache0 is None:
-            self._steady_cache0 = size
-        elif size > self._steady_cache0:
-            self.steady_recompiles += size - self._steady_cache0
-            self._steady_cache0 = size
-        return out
+        with self.recorder.phase("tick"):
+            with sanitize.guarded():
+                out = self._tick_inner()
+            size = self._program_cache_size()
+            if self._steady_cache0 is None:
+                self._steady_cache0 = size
+            elif size > self._steady_cache0:
+                self.steady_recompiles += size - self._steady_cache0
+                self._steady_cache0 = size
+            return out
 
     def _tick_inner(self) -> int:
+        rec = self.recorder
         t0 = time.perf_counter()
         self._maybe_rotate()
-        self._refill()
+        with rec.phase("refill"):
+            self._refill()
         ran = False
         round_rung = 0
-        for ti, tier in enumerate(self._tiers):
-            busy = tier.busy
-            if not busy:
-                continue
-            if ti not in self._warmed_tiers:
-                # This tier's first dispatch compiles its width's program.
-                self._warmed_tiers.add(ti)
-                self._note_new_program_config()
-            round_rung = max(round_rung, tier.width)
-            step, args, kw = self._tier_program(tier)
-            tier.state = step(*args, **kw)
-            self.dispatches += 1
-            self.lane_ticks_busy += busy * self.ticks_per_sync
-            self._active_frac_sum += busy / self.tier_lanes
-            ran = True
-        # Phase I: grouped blocks ride the same scheduling round -- one
-        # shared-scan dispatch per block, however many groups it holds.
-        for blk in self._blocks.values():
-            blk.state = fused_step(
-                self._values, self._goffsets, blk.state, blk.params,
-                num_ticks=self.ticks_per_sync, seg_cap=self._gseg_cap,
-                **self._spec)
-            self.dispatches += 1
-            self.block_ticks += self.ticks_per_sync
-            ran = True
+        with rec.phase("dispatch"):
+            for ti, tier in enumerate(self._tiers):
+                busy = tier.busy
+                if not busy:
+                    continue
+                if ti not in self._warmed_tiers:
+                    # This tier's first dispatch compiles its width's
+                    # program.
+                    self._warmed_tiers.add(ti)
+                    self._note_new_program_config()
+                round_rung = max(round_rung, tier.width)
+                step, args, kw = self._tier_program(tier)
+                tier.state = step(*args, **kw)
+                self.dispatches += 1
+                self.lane_ticks_busy += busy * self.ticks_per_sync
+                self._active_frac_sum += busy / self.tier_lanes
+                ran = True
+            # Phase I: grouped blocks ride the same scheduling round -- one
+            # shared-scan dispatch per block, however many groups it holds.
+            for blk in self._blocks.values():
+                step, args, kw = self._block_program(blk.state, blk.params)
+                blk.state = step(*args, **kw)
+                self.dispatches += 1
+                self.block_ticks += self.ticks_per_sync
+                ran = True
         if not ran:
             return 0
         self.ticks += self.ticks_per_sync
-        self._harvest()
-        self._harvest_blocks()
-        if self._slo is not None:
-            # Teach the cost model what a scheduling round costs at this
-            # compute rung (the harvest's device_get closed the round, so
-            # the wall time covers dispatch + sync).
-            self._slo.cost.observe_round(
-                time.perf_counter() - t0, self.ticks_per_sync, round_rung)
-        self._maybe_migrate()
+        with rec.phase("harvest"):
+            self._harvest()
+            self._harvest_blocks()
+            if self._slo is not None:
+                # Teach the cost model what a scheduling round costs at
+                # this compute rung (the harvest's device_get closed the
+                # round, so the wall time covers dispatch + sync).
+                self._slo.cost.observe_round(
+                    time.perf_counter() - t0, self.ticks_per_sync,
+                    round_rung)
+            self._maybe_migrate()
         return self.busy_lanes + self.busy_blocks
 
     def drain(self, max_ticks: int = 100_000) -> List[PoolResponse]:

@@ -59,6 +59,7 @@ from ..core.sampling import GroupedData, SampleStore
 from ..kernels import resolve_use_kernel
 from .lane_pool import GroupPoolResponse, LanePool
 from .planner import Planner, Route, fusable, grouped_fusable
+from .tracing import PhaseRecorder
 from .warm_cache import CachedAnswer, WarmCache, WarmEntry
 
 Array = jax.Array
@@ -213,6 +214,9 @@ class AQPSession:
         self.submitted = 0
         self.completed = 0
         self.pool_rebuilds = 0
+        # Phase spans and counters; shared with every pool built here, so
+        # they survive pool rebuilds.
+        self.recorder = PhaseRecorder()
 
     # -- public surface -----------------------------------------------------
     @property
@@ -243,20 +247,21 @@ class AQPSession:
                 f"wrap the Query: Request(query=...)")
         if request.rid in self._inflight or request.rid in self._results:
             raise ValueError(f"request id {request.rid} already submitted")
-        ticket = SessionTicket(rid=request.rid,
-                               submitted_s=time.perf_counter())
-        entry = _InFlight(ticket=ticket, request=request,
-                          key=None if key is None else np.asarray(key))
-        self._inflight[request.rid] = entry
-        self.submitted += 1
-        # Phase H: resolve the warm cache at submit time.  An explicitly
-        # pinned bootstrap key is a replay/repro contract the cache must
-        # not alias, so pinned requests bypass it entirely.
-        if self.cache is not None and entry.key is None \
-                and self._cache_resolve(entry):
-            return ticket       # exact replay: answered, zero dispatches
-        self._arrivals.append(request.rid)
-        return ticket
+        with self.recorder.phase("submit"):
+            ticket = SessionTicket(rid=request.rid,
+                                   submitted_s=time.perf_counter())
+            entry = _InFlight(ticket=ticket, request=request,
+                              key=None if key is None else np.asarray(key))
+            self._inflight[request.rid] = entry
+            self.submitted += 1
+            # Phase H: resolve the warm cache at submit time.  An explicitly
+            # pinned bootstrap key is a replay/repro contract the cache must
+            # not alias, so pinned requests bypass it entirely.
+            if self.cache is not None and entry.key is None \
+                    and self._cache_resolve(entry):
+                return ticket   # exact replay: answered, zero dispatches
+            self._arrivals.append(request.rid)
+            return ticket
 
     def _cache_resolve(self, entry: _InFlight) -> bool:
         """Submit-time cache lookup.  True = the request was answered
@@ -333,19 +338,25 @@ class AQPSession:
     def pump(self) -> int:
         """One non-blocking scheduler round: re-tune, admit arrivals, tick
         busy tiers once, harvest retirees.  Returns requests in flight."""
-        self._retune()
-        self._admit()
-        pool = self._pool
-        if pool is not None and (pool.busy_lanes or pool.busy_blocks
-                                 or pool.queue_depth):
-            d0 = pool.dispatches
-            pool.tick()
-            self.fused_dispatches += pool.dispatches - d0
-        # Unconditional: a shed request (phase J) is pilot-answered inside
-        # submit()/tick() without ever occupying a lane, so the pool can
-        # hold results while reporting zero busy lanes and an empty queue.
-        self._harvest_pool()
-        return self.in_flight
+        rec = self.recorder
+        with rec.phase("pump"):
+            with rec.phase("retune"):
+                self._retune()
+            with rec.phase("admit"):
+                self._admit()
+            pool = self._pool
+            if pool is not None and (pool.busy_lanes or pool.busy_blocks
+                                     or pool.queue_depth):
+                d0 = pool.dispatches
+                pool.tick()
+                self.fused_dispatches += pool.dispatches - d0
+            # Unconditional: a shed request (phase J) is pilot-answered
+            # inside submit()/tick() without ever occupying a lane, so the
+            # pool can hold results while reporting zero busy lanes and an
+            # empty queue.
+            with rec.phase("collect"):
+                self._harvest_pool()
+            return self.in_flight
 
     def drain(self, max_pumps: int = 100_000) -> List[SessionResponse]:
         """Pump until nothing is in flight; pop and return every finished
@@ -380,6 +391,10 @@ class AQPSession:
             "rows_touched": self.rows_touched,
             "pool_rebuilds": self.pool_rebuilds,
             "sample_epoch": self._epoch_counter,
+            # Where a pump's host time goes: self time and calls per phase
+            # span (tracing.PhaseRecorder), and the blocking fetches.
+            "phases": self.recorder.stats(),
+            "syncs": self.recorder.syncs,
         }
         if self.cache is not None:
             out["cache_hits"] = self.cache.hits
@@ -453,7 +468,7 @@ class AQPSession:
             tiers=self.pool_tiers, data_shards=self.data_shards,
             mesh=self.mesh, degrade=self.degrade, wfq=self.wfq,
             tenant_weights=self.tenant_weights, migrate=self.migrate,
-            max_degrade=self.max_degrade)
+            max_degrade=self.max_degrade, recorder=self.recorder)
         self.planner.built_pool(lanes)
         return pool
 
@@ -524,12 +539,15 @@ class AQPSession:
                 groups.get(Route.WARM, [])
             if pooled_entries:
                 self._admit_pool(pooled_entries)
-            if Route.BATCHED in groups:
-                self._run_batched(groups[Route.BATCHED])
-            if Route.LOOP in groups:
-                self._run_loop(groups[Route.LOOP])
-            for e in groups.get(Route.HOST, ()):
-                self._run_host(e)
+            if groups.keys() & {Route.BATCHED, Route.LOOP, Route.HOST}:
+                # The synchronous routes stall every lane until they return.
+                with self.recorder.phase("inline_route"):
+                    if Route.BATCHED in groups:
+                        self._run_batched(groups[Route.BATCHED])
+                    if Route.LOOP in groups:
+                        self._run_loop(groups[Route.LOOP])
+                    for e in groups.get(Route.HOST, ()):
+                        self._run_host(e)
         except BaseException:
             # A synchronous route died mid-wave (engine error, interrupt).
             # Entries not yet completed and not handed to the pool would
@@ -671,11 +689,10 @@ class AQPSession:
             t0 = time.perf_counter()
             res = self._dispatch_fused(
                 func, [e.request.query for e in group], keys)
-            theta = np.asarray(res.theta)          # forces the dispatch
-            errs, succ = np.asarray(res.error), np.asarray(res.success)
-            ns, rows = np.asarray(res.n), np.asarray(res.rows_sampled)
-            betas, fails = np.asarray(res.beta), np.asarray(res.failed)
-            its = np.asarray(res.iterations)
+            theta, errs, succ, ns, rows, betas, fails, its = \
+                self.recorder.device_get(
+                    (res.theta, res.error, res.success, res.n,
+                     res.rows_sampled, res.beta, res.failed, res.iterations))
             per_q = (time.perf_counter() - t0) / len(group)
             for lane, e in enumerate(group):
                 self._fused_rows += int(rows[lane])
@@ -696,20 +713,20 @@ class AQPSession:
             for e, key in zip(group, keys):
                 t0 = time.perf_counter()
                 res = self._dispatch_fused(func, [e.request.query], [key])
-                theta = np.asarray(res.theta)
-                rows = int(np.asarray(res.rows_sampled)[0])
+                theta, err, succ, n, rows, beta, failed, its = \
+                    self.recorder.device_get(
+                        (res.theta, res.error, res.success, res.n,
+                         res.rows_sampled, res.beta, res.failed,
+                         res.iterations))
+                rows = int(rows[0])
                 self._fused_rows += rows
                 self._cache_insert(
-                    e, beta=np.asarray(res.beta)[0], n=np.asarray(res.n)[0],
-                    theta=theta[0], error=float(np.asarray(res.error)[0]),
-                    success=bool(np.asarray(res.success)[0]),
-                    failed=bool(np.asarray(res.failed)[0]),
-                    iterations=int(np.asarray(res.iterations)[0]))
+                    e, beta=beta[0], n=n[0], theta=theta[0],
+                    error=float(err[0]), success=bool(succ[0]),
+                    failed=bool(failed[0]), iterations=int(its[0]))
                 self._complete(
-                    e, theta=theta[0],
-                    error=float(np.asarray(res.error)[0]),
-                    success=bool(np.asarray(res.success)[0]),
-                    n=np.asarray(res.n)[0],
+                    e, theta=theta[0], error=float(err[0]),
+                    success=bool(succ[0]), n=n[0],
                     wall_time_s=time.perf_counter() - t0, queue_wait_s=0.0,
                     route=Route.LOOP, rows_sampled=rows)
 
@@ -738,17 +755,20 @@ class AQPSession:
         (predicates fold into the measure, relative bounds resolve against
         the pilot) and every grouped request of a sharded session."""
         res = self.engine.execute(entry.request.query)
-        theta = np.asarray(res.theta)[:, 0]
-        gerr, gok = np.asarray(res.error), np.asarray(res.success)
-        n = np.asarray(res.n)
-        rows = int(np.asarray(res.rows_sampled).sum())
+        theta, gerr, gok, n, rows, beta, failed, its = \
+            self.recorder.device_get(
+                (res.theta, res.error, res.success, res.n, res.rows_sampled,
+                 res.beta, res.failed, res.iterations))
+        theta = np.asarray(theta)[:, 0]
+        gerr, gok, n = np.asarray(gerr), np.asarray(gok), np.asarray(n)
+        rows = int(np.asarray(rows).sum())
         self._fused_rows += rows
         self.fused_dispatches += 1
         self._cache_insert(
-            entry, beta=np.asarray(res.beta), n=n, theta=theta,
+            entry, beta=np.asarray(beta), n=n, theta=theta,
             error=float(gerr.max()), success=bool(gok.all()),
-            failed=bool(np.asarray(res.failed).any()),
-            iterations=int(np.asarray(res.iterations).max()),
+            failed=bool(np.asarray(failed).any()),
+            iterations=int(np.asarray(its).max()),
             group_error=gerr, group_success=gok)
         self._complete(
             entry, theta=theta, error=float(gerr.max()),
