@@ -424,6 +424,26 @@ def scoped(name: str):
     return wrap
 
 
+@jax.jit
+def as_columns(values: Array) -> Tuple[Array, ...]:
+    """The table as the single-shard step reads it: ``c`` 1-D columns.
+
+    A row gather from a 2-D ``(N, c)`` table makes XLA relayout the whole
+    table into the gather's 1-D form on every use -- inside the tick, once
+    per lane -- so the step takes the columns, built once per table.  A
+    tuple, not one c-major vector: ``col * N + row`` overflows int32 at
+    scale.  Jitted, so that the columns are written in one program, with
+    no whole-table intermediate between the slice and the relayout.
+    """
+    return tuple(values[:, j] for j in range(values.shape[1]))
+
+
+def _gather_rows(cols: Tuple[Array, ...], idx: Array) -> Array:
+    """Rows ``idx`` of the column table, ``idx.shape + (c,)`` like
+    ``values[idx]`` on the ``(N, c)`` table."""
+    return jnp.stack([col[idx] for col in cols], axis=-1)
+
+
 def lane_active(state: LaneState, max_iters: int) -> Array:
     """(q,) lanes still iterating: not converged, not failed, ticks left."""
     return ~state.done & ~state.failed & (state.k < max_iters)
@@ -517,9 +537,12 @@ def _lane_epilogue(s: LaneState, p: LaneParams, *, max_iters, active,
     )
 
 
-def _segment_tick(values, s, p, *, active, win_lo, win_hi, seeds, est,
+def _segment_tick(cols, s, p, *, active, win_lo, win_hi, seeds, est,
                   B, n_max, n_cap, ext_cap, seg_cap, metric, use_kernel):
     """Shared-scan SAMPLE + ESTIMATE of a grouped lane block (phase I).
+
+    ``cols`` is the table as :func:`as_columns` gives it; the packed gather
+    reads each column at the packed row indices.
 
     The block is ``q`` lanes of ``m = 1`` -- lane g bound to group g via its
     row of the stratified slot tables.  One PACKED gather over all active
@@ -563,7 +586,7 @@ def _segment_tick(values, s, p, *, active, win_lo, win_hi, seeds, est,
                 slot_j = filled0[lane_j] + (j - g_starts[lane_j])
                 valid = j < g_total
                 gidx = p.slot_idx[lane_j, 0, jnp.minimum(slot_j, n_cap - 1)]
-                rows = values[gidx]                            # (L, c)
+                rows = _gather_rows(cols, gidx)                # (L, c)
                 tgt = jnp.where(valid, slot_j, n_cap)          # OOB -> drop
                 return buf_b.at[lane_j, 0, tgt].set(rows, mode="drop")
             return branch
@@ -604,7 +627,7 @@ def _segment_tick(values, s, p, *, active, win_lo, win_hi, seeds, est,
 
 
 def _step_body(
-    values: Array,
+    cols: Tuple[Array, ...],
     offsets: Array,
     s: LaneState,
     p: LaneParams,
@@ -645,6 +668,9 @@ def _step_body(
     scanned, not ``q x`` the global width bucket.  Decision structure,
     windows, weights, and seeds are identical to the generic path; only
     the f32 summation order of the moment sums differs.
+
+    ``cols`` is the table as :func:`as_columns` gives it: every row gather
+    reads the 1-D columns, so no tick relayouts the table.
     """
     est = get_estimator(est_name) if est_name is not None else None
     m = offsets.shape[0] - 1
@@ -700,7 +726,7 @@ def _step_body(
             jnp.arange(m, dtype=jnp.uint32)[None, :],
             jnp.uint32(_SALT_GROUP))                           # (q, m)
         buf, filled, e_b, theta_b = _segment_tick(
-            values, s, p, active=active, win_lo=win_lo, win_hi=win_hi,
+            cols, s, p, active=active, win_lo=win_lo, win_hi=win_hi,
             seeds=seeds, est=est, B=B, n_max=n_max, n_cap=n_cap,
             ext_cap=ext_cap, seg_cap=seg_cap, metric=metric,
             use_kernel=use_kernel)
@@ -717,7 +743,7 @@ def _step_body(
         valid = slots < hi_l[:, None]
         clipped = jnp.minimum(slots, n_cap - 1)
         gidx = jnp.take_along_axis(slot_idx_l, clipped, axis=1)
-        new_rows = values[gidx]                                # (m, ext, c)
+        new_rows = _gather_rows(cols, gidx)                    # (m, ext, c)
         tgt = jnp.where(valid, slots, n_cap)                   # OOB -> dropped
         return buf_l.at[jnp.arange(m)[:, None], tgt].set(
             new_rows, mode="drop")
@@ -1205,7 +1231,7 @@ _STEP_STATICS = (
          static_argnames=_STEP_STATICS + ("num_ticks", "data_shards",
                                           "seg_window", "seg_cap"))
 def fused_step(
-    values: Array,
+    values: "Tuple[Array, ...] | Array",
     offsets: Array,
     state: LaneState,
     params: LaneParams,
@@ -1239,6 +1265,12 @@ def fused_step(
     needs a mid-window host check.  ``est_name=None`` selects each lane's
     estimator from ``params.est_fids`` (moment family only).
 
+    ``values`` is the table as :func:`as_columns` gives it -- a tuple of
+    ``c`` 1-D ``(N,)`` columns, built once per table, never per call -- so
+    the row gathers read the columns and the compiled step holds no
+    whole-table relayout.  The sharded body (``data_shards > 1``) takes its
+    padded ``(N_pad, c)`` table instead.
+
     ``data_shards > 1`` runs the SHARDED body (phase G) on one device --
     the solo-emulation reference whose answers the mesh step
     (:func:`make_sharded_step`) reproduces bit-equal.  It requires a
@@ -1260,6 +1292,10 @@ def fused_step(
     """
     if seg_window is not None and data_shards == 1:
         raise ValueError("seg_window applies to the sharded step only")
+    if (data_shards == 1) != isinstance(values, tuple):
+        raise TypeError(
+            "the single-shard step takes the table as as_columns(values); "
+            "the sharded step takes its padded (N_pad, c) table")
     if seg_cap is not None:
         if data_shards > 1:
             raise ValueError("seg_cap (grouped blocks) is single-shard only")
@@ -1541,10 +1577,11 @@ def _fused_l2miss_lanes1(
         max_iters=max_iters, n_cap=n_cap, backend=backend, metric=metric,
         growth_cap=growth_cap, ext_cap=ext_cap, adaptive=adaptive,
         use_kernel=use_kernel, gate_gather=gate_gather)
+    cols = as_columns(values)     # once per call, outside the loop
 
     state = jax.lax.while_loop(
         lambda st: jnp.any(lane_active(st, max_iters)),
-        lambda st: _step_body(values, offsets, st, params, **spec),
+        lambda st: _step_body(cols, offsets, st, params, **spec),
         state0)
     return lanes_result(state)
 
@@ -1625,9 +1662,10 @@ def _fused_grouped_closed(
         max_iters=max_iters, n_cap=n_cap, backend=backend, metric=metric,
         growth_cap=growth_cap, ext_cap=ext_cap, adaptive=adaptive,
         use_kernel=use_kernel, gate_gather=gate_gather, seg_cap=seg_cap)
+    cols = as_columns(values)     # once per call, outside the loop
     state = jax.lax.while_loop(
         lambda st: jnp.any(lane_active(st, max_iters)),
-        lambda st: _step_body(values, step_offsets, st, params, **spec),
+        lambda st: _step_body(cols, step_offsets, st, params, **spec),
         state0)
     return lanes_result(state)
 

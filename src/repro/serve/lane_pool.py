@@ -69,9 +69,10 @@ import numpy as np
 from ..aqp.query import Query
 from ..core import bootstrap
 from ..core import mesh as core_mesh
-from ..core.fused import (LaneParams, LaneState, ShardSpec, bucket_ladder,
-                          fused_step, grouped_seg_cap, init_lane_state,
-                          lane_boot_seed, make_group_lane_params,
+from ..core.fused import (LaneParams, LaneState, ShardSpec, as_columns,
+                          bucket_ladder, fused_step, grouped_seg_cap,
+                          init_lane_state, lane_boot_seed,
+                          make_group_lane_params,
                           make_lane_params, make_shard_spec,
                           make_sharded_lane_params, make_sharded_step,
                           resolve_ext_cap, resolve_seg_window, scoped,
@@ -390,9 +391,10 @@ class LanePool:
                     raise ValueError(
                         f"mesh has {self._mesh.devices.size} devices; pool "
                         f"wants data_shards={self.data_shards}")
-            padded = self._layout.pad_values(np.asarray(data.values))
-            self._values = (jnp.asarray(padded) if self._mesh is None else
-                            core_mesh.put_sharded(self._mesh, padded))
+            with self.recorder.phase("table_layout"):
+                padded = self._layout.pad_values(np.asarray(data.values))
+                self._values = (jnp.asarray(padded) if self._mesh is None
+                                else core_mesh.put_sharded(self._mesh, padded))
             sspec = make_shard_spec(self._layout)
             if self._mesh is not None:
                 sspec = ShardSpec(
@@ -412,7 +414,10 @@ class LanePool:
         else:
             self._layout = None
             self._mesh = None
-            self._values = data.values
+            # Built once per pool: the step reads 1-D columns, so no tick
+            # relayouts the table.
+            with self.recorder.phase("table_layout"):
+                self._values = as_columns(data.values)
             self._spec = dict(
                 est_name=None, B=B, n_min=n_min, n_max=n_max,
                 l=int(l if l is not None else min(m + 2, 12)), tau=1e-3,
@@ -1030,9 +1035,12 @@ class LanePool:
                 return
 
     @property
-    def values(self) -> Array:
-        """The resident table as the tiers read it: row-sharded over the
-        mesh (and padded to the shard layout) when ``data_shards > 1``."""
+    def values(self) -> "tuple | Array":
+        """The resident table as the tiers and blocks read it, built once
+        in ``__init__`` (phase ``table_layout``): a tuple of ``c`` 1-D
+        ``(N,)`` columns (:func:`~repro.core.fused.as_columns`) on one
+        device; the ``(N_pad, c)`` table padded to the shard layout (and
+        row-sharded over the mesh) when ``data_shards > 1``."""
         return self._values
 
     @property

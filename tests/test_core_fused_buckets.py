@@ -87,8 +87,9 @@ def test_gated_gather_rows_accounting(data):
     """In the gated path ``rows_sampled`` must still equal the final filled
     watermark exactly: only ACTIVE ticks gather, and each gathers exactly
     its window's worth of new rows."""
-    from repro.core.fused import (fused_step, init_lane_state, lane_active,
-                                  lanes_result, make_lane_params)
+    from repro.core.fused import (as_columns, fused_step, init_lane_state,
+                                  lane_active, lanes_result,
+                                  make_lane_params)
 
     q = 3
     keys = jax.random.split(jax.random.PRNGKey(5), q)
@@ -102,9 +103,10 @@ def test_gated_gather_rows_accounting(data):
     state = init_lane_state(keys, 2, n_cap=KW["n_cap"], c_dim=1, p_dim=1,
                             n_min=KW["n_min"], max_iters=KW["max_iters"],
                             dtype=data.values.dtype)
+    cols = as_columns(data.values)
     while bool(np.any(np.asarray(lane_active(state, KW["max_iters"])))):
-        state = fused_step(data.values, offsets, state, params,
-                           gate_gather=True, **kw)
+        state = fused_step(cols, offsets, state, params, gate_gather=True,
+                           **kw)
     res = lanes_result(state)
     assert np.array_equal(np.asarray(res.rows_sampled),
                           np.asarray(state.filled).sum(axis=1))

@@ -18,9 +18,9 @@ from numpy.testing import assert_allclose
 
 from repro.aqp.query import Query
 from repro.core import estimators, fused
-from repro.core.fused import (fused_l2miss, fused_l2miss_lanes, fused_step,
-                              init_lane_state, lane_active, lanes_result,
-                              make_lane_params)
+from repro.core.fused import (as_columns, fused_l2miss, fused_l2miss_lanes,
+                              fused_step, init_lane_state, lane_active,
+                              lanes_result, make_lane_params)
 from repro.data import make_grouped
 from repro.serve.lane_pool import LanePool
 
@@ -70,8 +70,9 @@ def test_step_matches_while_loop(data):
                             n_min=SPEC["n_min"], max_iters=SPEC["max_iters"],
                             dtype=data.values.dtype)
     ticks = 0
+    cols = as_columns(data.values)
     while bool(np.any(np.asarray(lane_active(state, SPEC["max_iters"])))):
-        state = fused_step(data.values, offsets, state, params, **kw)
+        state = fused_step(cols, offsets, state, params, **kw)
         ticks += 1
     r_step = lanes_result(state)
 
@@ -108,12 +109,13 @@ def test_multi_tick_dispatch_matches_single(data):
             n_min=SPEC["n_min"], max_iters=SPEC["max_iters"],
             dtype=data.values.dtype)
 
+    cols = as_columns(data.values)
     s1 = fresh()
     for _ in range(8):
-        s1 = fused_step(data.values, offsets, s1, params, **kw)
+        s1 = fused_step(cols, offsets, s1, params, **kw)
     s4 = fresh()
     for _ in range(2):
-        s4 = fused_step(data.values, offsets, s4, params, num_ticks=4, **kw)
+        s4 = fused_step(cols, offsets, s4, params, num_ticks=4, **kw)
     r1, r4 = lanes_result(s1), lanes_result(s4)
     assert np.array_equal(np.asarray(r1.n), np.asarray(r4.n))
     assert np.array_equal(np.asarray(r1.iterations), np.asarray(r4.iterations))
@@ -338,3 +340,46 @@ def test_service_pool_mode_mixed_funcs(data):
     for q, r in zip(qs[:4], rs):
         truth = svc.engine.exact(q).ravel()
         assert np.linalg.norm(r.theta.ravel() - truth) <= 2 * q.epsilon
+
+
+def test_column_table_gathers_the_row_major_rows():
+    """The pool hands the step its table as 1-D columns, built once: after
+    a few ticks every lane's buffer holds, bit for bit, the rows a NumPy
+    gather of the row-major two-column table gives at the lane's slots --
+    for the solo tier and for a grouped block alike."""
+    from repro.core.sampling import GroupedData
+
+    rng = np.random.default_rng(4)
+    sizes = [30_000, 20_000]
+    vals = np.stack([rng.normal(5.0, 1.0, sum(sizes)),
+                     rng.exponential(3.0, sum(sizes))],
+                    axis=1).astype(np.float32)
+    table = GroupedData(vals, np.cumsum([0] + sizes))
+    pool = LanePool(table, lanes=2, tiers=1, **SPEC, seed=3)
+    assert isinstance(pool.values, tuple) and len(pool.values) == 2
+    assert pool.recorder.stats()["table_layout"]["calls"] == 1
+    for eps in (0.02, 0.03):
+        pool.submit(Query(func="avg", epsilon=eps))
+    pool.submit_group(Query(func="avg", epsilon=0.02, group_by=True))
+    for _ in range(3):
+        pool.tick()
+    assert pool.recorder.stats()["table_layout"]["calls"] == 1
+
+    def expected(slot_idx, filled, shape):
+        want = np.zeros(shape, np.float32)
+        for i, g in np.ndindex(filled.shape):
+            f = filled[i, g]
+            want[i, g, :f] = vals[slot_idx[i, g, :f]]
+        return want
+
+    tier = pool._tiers[0]
+    blk = next(iter(pool._blocks.values()))
+    for state, params in ((tier.state, tier.params), (blk.state, blk.params)):
+        buf = np.asarray(state.buf)
+        filled = np.asarray(state.filled)
+        slot_idx = np.asarray(params.slot_idx)
+        if slot_idx.ndim == 2:                       # one shared binding
+            slot_idx = np.broadcast_to(slot_idx, filled.shape + (
+                slot_idx.shape[-1],))
+        assert np.all(filled > 0)
+        assert buf.tobytes() == expected(slot_idx, filled, buf.shape).tobytes()

@@ -5,7 +5,7 @@ name scopes of the fused step.
     non-negative self time, and the self times add up to the wall time of
     the calls that opened them;
   * the counters belong to the session, so they keep growing across a pool
-    rebuild;
+    rebuild, and ``table_layout`` counts one call per pool built;
   * under a profiler the ``miss.*`` spans nest submit / pump > (retune,
     admit, tick > (refill, dispatch, harvest), collect), with ``sync`` under
     the phase that fetched;
@@ -38,6 +38,7 @@ PARENTS = {
     "collect": {"pump"}, "inline_route": {"admit"},
     "refill": {"tick"}, "dispatch": {"tick"}, "harvest": {"tick"},
     "sync": {"admit", "refill", "harvest", "inline_route"},
+    "table_layout": {"admit", "retune"},
 }
 
 
@@ -86,6 +87,8 @@ def test_phase_counters_after_a_short_session(data):
     # One key fetch per pooled request, plus the harvest fetches.
     assert st["syncs"] >= len(SPECS) + ph["tick"]["calls"]
     assert ph["tick"]["calls"] <= ph["pump"]["calls"]
+    # The table's column form is built once per pool, never per tick.
+    assert ph["table_layout"]["calls"] == 1
 
 
 def test_self_times_add_up_to_the_wall_time(data):
@@ -105,6 +108,7 @@ def test_counters_survive_a_pool_rebuild(data):
     ph1, syncs1 = sess.stats()["phases"], sess.stats()["syncs"]
     assert sess.pool_rebuilds == 1
     assert sess.pool.recorder is sess.recorder
+    assert ph1["table_layout"]["calls"] == 1 + sess.pool_rebuilds
     # The new pool counts its own ticks only; the session counts them all.
     assert sess.pool.ticks < ph1["tick"]["calls"]
     assert syncs1 > syncs0

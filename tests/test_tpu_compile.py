@@ -7,17 +7,31 @@ topology -- no chip attached -- at the widths the lane pool runs (2^16
 slots, B=300), and check that the program holds the Pallas kernel
 (``tpu_custom_call``).  The topology is described inside a fixture, so
 only the worker that runs this file loads the TPU compiler.
+
+The lane pool's tier and grouped block steps are compiled the same way at
+the row count of TPC-H lineitem SF 10, to check that the table reaches the
+row gathers as it is passed in, with no whole-table relayout in the tick.
 """
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.aqp.query import Query
+from repro.core.sampling import GroupedData
 from repro.kernels.poisson_bootstrap import kernel as pb_kernel
 from repro.kernels.poisson_bootstrap import ops as pb_ops
 from repro.kernels.segment_agg import kernel as seg_kernel
+from repro.kernels.segment_agg import ops as seg_ops
+from repro.serve.lane_pool import LanePool
 
 WIDTH = 1 << 16     # the pool's n_cap: slots per (lane, group)
+SF10_ROWS = 59_986_052
+# L_RETURNFLAG's groups A, N, R at SF 10 (24.7%, 50.6%, 24.7%).
+SF10_OFFSETS = [0, 14_816_555, 45_169_497, SF10_ROWS]
 
 
 @pytest.fixture(scope="module")
@@ -80,3 +94,55 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     fn, args = _program(name, one_chip)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# Opcodes that may carry a whole-table shape without moving the table:
+# the parameters, tuples and control flow that pass it through to the
+# gathers, and the gathers themselves.
+_PASS_THROUGH = {"parameter", "tuple", "get-tuple-element", "while",
+                 "conditional", "call", "gather"}
+_INSTR = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (.+?) ([a-z][\w\-]*)\(")
+
+
+def _whole_table_ops(hlo_text):
+    """``(opcode, line)`` of each instruction whose result has the table's
+    row count and which is not a pass-through or a gather."""
+    out = []
+    for line in hlo_text.splitlines():
+        m = _INSTR.match(line)
+        if (m and re.search(rf"\b{SF10_ROWS}\b", m.group(1))
+                and m.group(2) not in _PASS_THROUGH):
+            out.append((m.group(2), line.strip()[:160]))
+    return out
+
+
+@pytest.mark.parametrize("c", [1, 2])
+@pytest.mark.parametrize("grouped", [False, True], ids=["tier", "block"])
+def test_pool_step_has_no_whole_table_op(one_chip, grouped, c):
+    """The pool's step at SF 10 (59,986,052 rows, abstract, no data) at the
+    pool's default lanes, tiers and ``n_cap``, B=300, compiled for a v5e
+    with the kernels in: only the row gathers touch the table."""
+    # A stand-in table with SF 10's groups: the pool's shapes follow the
+    # offsets, the step's table argument is replaced below.
+    data = GroupedData(jnp.zeros((8, c), jnp.float32), SF10_OFFSETS)
+    with pytest.MonkeyPatch.context() as mp:
+        for ops in (pb_ops, seg_ops):
+            mp.setattr(ops, "interpret_default", lambda: False)
+        pool = LanePool(data, B=300, use_kernel=True)
+        if grouped:
+            pool.submit_group(Query(func="avg", epsilon=0.05,
+                                    group_by=True))
+            step, args, kw = pool._block_program(*pool._block_shapes)
+        else:
+            step, args, kw = pool._tier_program(pool._tiers[0])
+        assert isinstance(args[0], tuple) and len(args[0]) == c
+        cols = tuple(jax.ShapeDtypeStruct((SF10_ROWS,), jnp.float32,
+                                          sharding=one_chip)
+                     for _ in range(c))
+        rest = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                           sharding=one_chip), args[1:])
+        txt = step.lower(cols, *rest, **kw).compile().as_text()
+    assert "tpu_custom_call" in txt
+    assert re.search(rf"\bgather\(", txt)
+    assert _whole_table_ops(txt) == []
