@@ -19,7 +19,8 @@ to I):
 This module recomputes ``theta`` and ``error`` from the table in float64
 NumPy, with nothing of the program imported: it rebuilds the keys with
 ``jax.random`` from the seeds the benchmark chose, finds the sample epoch
-and the window by ``theta`` (a wrong one is off by about 1e-3), and the tick
+and the window by ``theta`` (a wrong one is as a rule off by about 1e-3;
+where two come within ``THETA_SLACK``, the error bar decides), and the tick
 by ``error`` (a wrong tick is off by about 1e-2).  The tick search runs in
 float32 on the device; ticks it cannot tell apart, and the numbers compared,
 are float64.
@@ -49,6 +50,7 @@ MEAN_FUNCS = ("avg", "sum", "count", "proportion")
 SCALED_FUNCS = ("sum", "count")
 ROW_CHUNK = 8192
 TICK_SLACK = 5e-3      # float32 search: candidates this close go to float64
+THETA_SLACK = 1e-4     # epochs and windows this close in theta: error bar
 
 
 # -- counter hash, NumPy ------------------------------------------------------
@@ -93,10 +95,12 @@ def fold_in(key, data: int) -> np.ndarray:
 
 
 def slot_rows(slot_seed: int, row_id: int, start: int, size: int,
-              n: int) -> np.ndarray:
-    """Table rows of slots ``0..n-1`` of one group (float32 arithmetic, as
-    the slot binding states it)."""
-    bits = hash3(slot_seed, row_id, np.arange(n, dtype=np.uint32))
+              n: int, first: int = 0) -> np.ndarray:
+    """Table rows of slots ``first..first+n-1`` of one group's extent
+    ``[start, start + size)`` (float32 arithmetic, as the slot binding
+    states it)."""
+    bits = hash3(slot_seed, row_id,
+                 np.arange(first, first + n, dtype=np.uint32))
     u = (bits >> np.uint32(8)).astype(np.int32).astype(np.float32) \
         * np.float32(2.0 ** -24)
     idx = (u * np.float32(size)).astype(np.int32)
@@ -145,24 +149,30 @@ def replicate_moments(x: np.ndarray, slots: np.ndarray, seed: int, B: int,
     return M
 
 
-def estimate(xs: Sequence[np.ndarray], slots: Sequence[np.ndarray],
-             seeds: Sequence[int], func: str, B: int, *,
+def estimate(parts: Sequence[Sequence[Tuple[np.ndarray, np.ndarray, int]]],
+             func: str, B: int, *,
              control: bool = False) -> Tuple[np.ndarray, np.ndarray]:
     """``(theta (m,), replicates (m, B))`` of one lane, unscaled.
 
-    ``control`` computes the whole estimate on bfloat16 operands (float32
-    products and float64 sums), the precision below the configuration's.
+    ``parts[g]`` lists group g's ``(x, slots, seed)``: its values, their
+    slot positions and their bootstrap seed, one part a shard (one in all
+    on a single shard).  The parts' moment sums are added in float64, then
+    the dead-replicate guard and the finish run on the sum.  ``control``
+    computes the whole estimate on bfloat16 operands (float32 products and
+    float64 sums), the precision below the configuration's.
     """
     thetas, reps = [], []
-    for x, s, seed in zip(xs, slots, seeds):
-        x64 = np.asarray(x, np.float32).astype(np.float64)
-        if control:
-            x2 = bf16_round(np.float32(x64) * np.float32(x64))
-            x64 = bf16_round(x64)
-        else:
-            x2 = x64 * x64
-        plain = np.array([len(x64), x64.sum(), x2.sum()])
-        M = replicate_moments(x, s, seed, B, control=control)
+    for group in parts:
+        plain, M = np.zeros(3), np.zeros((B, 3))
+        for x, s, seed in group:
+            x64 = np.asarray(x, np.float32).astype(np.float64)
+            if control:
+                x2 = bf16_round(np.float32(x64) * np.float32(x64))
+                x64 = bf16_round(x64)
+            else:
+                x2 = x64 * x64
+            plain += [len(x64), x64.sum(), x2.sum()]
+            M += replicate_moments(x, s, seed, B, control=control)
         M = np.where(M[:, :1] <= 0, plain[None, :], M)
         thetas.append(_finish(plain, func))
         reps.append(_finish(M, func))
@@ -193,15 +203,23 @@ def _hash3_j(seed, row, col):
                ^ seed.astype(u32) * np.uint32(C_SEED))
 
 
-@functools.partial(jax.jit, static_argnames=("B", "var"))
-def _search_errors(x, lo, hi, seeds, scale, q, *, B: int, var: bool):
-    """Error bars of one lane for each candidate seed row ``(C, m)``."""
+@functools.partial(jax.jit, static_argnames=("B", "var", "shards"))
+def _search_errors(x, lo, hi, seeds, scale, q, *, B: int, var: bool,
+                   shards: int):
+    """Error bars of one lane for each candidate seed row ``(C, m S)``.
+
+    Each group has ``shards`` S rows of ``x`` (its part on each shard, row
+    ``g * S + s``, with the shard's own seed and slot positions), whose
+    moment sums are added before the finish.
+    """
     m, W = x.shape
     j = jnp.arange(W, dtype=jnp.int32)
     mask = ((j[None] >= lo[:, None]) & (j[None] < hi[:, None])).astype(
         jnp.float32)
     feats = jnp.stack([mask, mask * x, mask * x * x], -1)         # (m, W, 3)
     plain = feats.sum(1)                                           # (m, 3)
+    if shards > 1:
+        plain = plain.reshape(-1, shards, 3).sum(1)              # (m, 3)
     cols = jnp.arange(B, dtype=jnp.uint32)
 
     def fin(M):
@@ -218,6 +236,8 @@ def _search_errors(x, lo, hi, seeds, scale, q, *, B: int, var: bool):
         w = sum((v >= t).astype(jnp.float32) for t in THRESH.tolist())
         M = jnp.einsum("mwb,mwp->mbp", w, feats,
                        precision=jax.lax.Precision.HIGHEST)
+        if shards > 1:
+            M = M.reshape(-1, shards, B, 3).sum(1)
         M = jnp.where(M[..., :1] <= 0, plain[:, None, :], M)
         dev = (fin(M) - theta[:, None]) * scale[:, None]
         return jnp.quantile(jnp.sqrt(jnp.sum(dev * dev, 0)), q)
@@ -292,9 +312,11 @@ class Reference:
                 seed, row_id = key_bits(fold_in(sample_key, g), SALT_SLOT), 0
             else:
                 seed, row_id = key_bits(sample_key, SALT_SLOT), g
-            n = int(min(self.n_cap, self.sizes[g]))
+            # n_cap slots whatever the group's size: the slots of a group
+            # smaller than n_cap bind its rows again (the init design's
+            # stacked windows reach past them).
             self._rows[k] = slot_rows(seed, row_id, int(self.offsets[g]),
-                                      int(self.sizes[g]), n)
+                                      int(self.sizes[g]), self.n_cap)
         return self._rows[k]
 
     # -- windows
@@ -324,6 +346,22 @@ class Reference:
     def scale_of(self, func: str, g: int) -> float:
         return float(self.sizes[g]) if func in SCALED_FUNCS else 1.0
 
+    # -- the window's parts and their bootstrap seeds
+    def parts(self, epoch: int, grouped: bool, group_ids: Sequence[int],
+              lo: np.ndarray, hi: np.ndarray
+              ) -> List[List[Tuple[np.ndarray, int, int]]]:
+        """Per group, its window's parts ``(x, a, b)``, one per shard: the
+        values of slots ``[a, b)`` (one part on a single shard)."""
+        return [[(self.values[self.rows(epoch, grouped, g)[a:b]], int(a),
+                  int(b))] for g, a, b in zip(group_ids, lo, hi)]
+
+    def seeds(self, bases: Sequence[int], k: int, grouped: bool,
+              group_ids: Sequence[int]) -> List[List[int]]:
+        """``(m, S)`` bootstrap seeds of each group's part on each shard at
+        tick ``k``."""
+        return [[int(hash3(hash3(b, k, SALT_GROUP), 0 if grouped else i,
+                           SALT_GROUP))] for i, b in enumerate(bases)]
+
     # -- checks
     def check_lane(self, s: Served, epochs: int, group_ids: Sequence[int],
                    key_per_group: Sequence[np.ndarray], theta: np.ndarray,
@@ -331,14 +369,13 @@ class Reference:
                    control: bool = False
                    ) -> Tuple[float, float, float, float, int, int]:
         """One lane (a solo answer, or one group of a grouped block)."""
-        m = len(group_ids)
         scale = np.array([self.scale_of(s.func, g) for g in group_ids])
         windows: Dict[Tuple, List[int]] = {}
         for k in range(self.max_iters):
             w = self.window(k, n, group_ids)
             if w is not None:
                 windows.setdefault((tuple(w[0]), tuple(w[1])), []).append(k)
-        best = (np.inf, None, None)
+        cands = []
         for e in range(epochs + 1):
             rows = [self.rows(e, grouped, g) for g in group_ids]
             for (lo, hi), ks in windows.items():
@@ -348,31 +385,55 @@ class Reference:
                     self.values[r[a:b]].astype(np.float64), s.func, sc) - t)
                     / max(abs(t), 1e-30)
                     for r, a, b, sc, t in zip(rows, lo, hi, scale, theta))
-                if gap < best[0]:
-                    best = (gap, e, (np.asarray(lo), np.asarray(hi), ks))
-        if best[1] is None:
+                cands.append((gap, e, np.asarray(lo), np.asarray(hi), ks))
+        if not cands:
             return np.inf, np.inf, np.inf, np.inf, -1, -1
-        _, epoch, (lo, hi, ks) = best
-        rows = [self.rows(epoch, grouped, g) for g in group_ids]
-        xs = [self.values[r[a:b]] for r, a, b in zip(rows, lo, hi)]
-        slots = [np.arange(a, b, dtype=np.uint32) for a, b in zip(lo, hi)]
+        # A wrong epoch or window is as a rule off by about 1e-3 in theta,
+        # but two epochs' samples can agree far closer than that: theta
+        # cannot tell apart candidates within THETA_SLACK of the best, and
+        # the error bar, drawn from the lane's own key, decides.
+        best = min(c[0] for c in cands)
+        return min((self._fit_ticks(s, e, lo, hi, ks, group_ids,
+                                    key_per_group, theta, error, grouped,
+                                    scale, control)
+                    for gap, e, lo, hi, ks in cands
+                    if gap <= best + THETA_SLACK), key=lambda out: out[1])
+
+    def _fit_ticks(self, s: Served, epoch: int, lo: np.ndarray,
+                   hi: np.ndarray, ks: List[int], group_ids: Sequence[int],
+                   key_per_group: Sequence[np.ndarray], theta: np.ndarray,
+                   error: float, grouped: bool, scale: np.ndarray,
+                   control: bool
+                   ) -> Tuple[float, float, float, float, int, int]:
+        """The gaps of one (epoch, window) candidate at the tick whose
+        error bar lies closest to the program's."""
+        m = len(group_ids)
+        parts = self.parts(epoch, grouped, group_ids, lo, hi)
+        S = len(parts[0])
         bases = [key_bits(kg, SALT_BOOT) for kg in key_per_group]
 
-        def seeds_at(k):
-            return [int(hash3(hash3(b, k, SALT_GROUP), 0 if grouped else i,
-                              SALT_GROUP)) for i, b in enumerate(bases)]
+        def parts_at(k):
+            return [[(x, np.arange(a, b, dtype=np.uint32), sd)
+                     for (x, a, b), sd in zip(pg, sg)]
+                    for pg, sg in zip(parts, self.seeds(bases, k, grouped,
+                                                        group_ids))]
 
         if len(ks) > 1:
-            W = _bucket(int(hi.max()))
-            xp = np.zeros((m, W), np.float32)
-            for i, (r, a, b) in enumerate(zip(rows, lo, hi)):
-                xp[i, a:b] = self.values[r[a:b]]
-            cand = np.asarray([seeds_at(k) for k in ks], np.uint32)
+            W = _bucket(max(b for pg in parts for _, _, b in pg))
+            xp = np.zeros((m * S, W), np.float32)
+            for i, pg in enumerate(parts):
+                for sh, (x, a, b) in enumerate(pg):
+                    xp[i * S + sh, a:b] = x
+            los = np.asarray([a for pg in parts for _, a, _ in pg], np.int32)
+            his = np.asarray([b for pg in parts for _, _, b in pg], np.int32)
+            cand = np.asarray([np.ravel(self.seeds(bases, k, grouped,
+                                                   group_ids)) for k in ks],
+                              np.uint32)
             errs = np.asarray(jax.device_get(_search_errors(
-                jnp.asarray(xp), jnp.asarray(lo, jnp.int32),
-                jnp.asarray(hi, jnp.int32), jnp.asarray(cand),
-                jnp.asarray(scale, jnp.float32),
-                jnp.float32(1.0 - s.delta), B=self.B, var=s.func == "var")))
+                jnp.asarray(xp), jnp.asarray(los), jnp.asarray(his),
+                jnp.asarray(cand), jnp.asarray(scale, jnp.float32),
+                jnp.float32(1.0 - s.delta), B=self.B, var=s.func == "var",
+                shards=S)))
             # The search centres on its own float32 theta, whose rounding
             # |theta| / error amplifies: it tells ticks apart only to about
             # 1e-3 of the error bar.  Ticks that close are told apart in
@@ -389,16 +450,15 @@ class Reference:
                     abs(e - (q := error_bar(reps, th / scale, scale,
                                             s.delta))) / max(q, 1e-30))
 
-        fits = {k: estimate(xs, slots, seeds_at(k), s.func, self.B)
-                for k in ks}
+        fits = {k: estimate(parts_at(k), s.func, self.B) for k in ks}
         ref_th = fits[ks[0]][0]            # theta is the same at every tick
         k = min(ks, key=lambda k: gaps(theta, error, fits[k][1])[1])
-        ref_reps, seeds = fits[k][1], seeds_at(k)
+        ref_reps = fits[k][1]
         out = list(gaps(theta, error, ref_reps)) + [np.nan, np.nan]
         if control:
             # The control: the reference on bfloat16 operands, put in the
             # program's place and judged as the program is.
-            c_th, c_reps = estimate(xs, slots, seeds, s.func, self.B,
+            c_th, c_reps = estimate(parts_at(k), s.func, self.B,
                                     control=True)
             out[2:] = gaps(c_th * scale,
                            error_bar(c_reps, c_th, scale, s.delta), ref_reps)

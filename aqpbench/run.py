@@ -13,14 +13,21 @@ a traffic mix (``aqpbench/workloads/<cell>.json``).  A run:
    ``.jax_cache/`` at the checkout root;
 3. makes the table on the device from ``--seed``, and its exact answers;
 4. builds the session with the contract's ``B`` and the default knobs;
+   a configuration with ``"data_shards": S`` (absent: 1; at most the
+   cell's ``chips``) gets its table row-sharded over the first S devices
+   and a session with ``data_shards=S`` on the program's default mesh;
 5. warms up with the cell's own traffic, counted as set-up;
 6. measures for ``--seconds`` (with ``--trace 1``, the first
-   ``TRACE_SECONDS`` under the profiler), then answers every request still
-   in flight;
+   ``TRACE_SECONDS`` under the profiler, and the untraced rest after the
+   profiler has stopped), then answers every request still in flight;
 7. checks every answer of the window against its own contract (success,
    error bar within epsilon), recomputes a seed-drawn sample of them in
-   float64 (``aqpbench/reference.py``), and prints one JSON result line
-   last on standard output.
+   float64, and prints one JSON result line last on standard output.  The
+   reference follows the layout the lane pool reports: one shard
+   (``aqpbench/reference.py``, the pool's ``TRAJECTORY``), or S row
+   shards (``aqpbench/reference_sharded.py``, ``TRAJECTORY_SHARDED``:
+   the proportional-emission slot merge, each shard's own slot binding
+   and bootstrap streams, the shards' sums added in float64).
 
 End-to-end metrics (``--trace 0``) and per-layer metrics (``--trace 1``)
 are the ``BENCHMARK.json`` entries that apply to the cell; each is read from
@@ -39,6 +46,7 @@ import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import resource  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
@@ -56,6 +64,8 @@ SAMPLE_SOLO, SAMPLE_GROUPED = 32, 12   # fused answers recomputed per run
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 FUSED_ROUTES = ("pool", "loop", "batched")
 TRAJECTORY = ("B", "n_min", "n_max", "n_cap", "max_iters", "l", "ext_cap")
+TRAJECTORY_SHARDED = ("B", "n_min", "n_max", "n_cap", "max_iters", "l",
+                      "seg_window", "data_shards")
 
 
 class RunError(Exception):
@@ -148,6 +158,14 @@ class Client:
             self._step()
 
 
+def _device_ids(x) -> List[int]:
+    return sorted(d.id for d in x.sharding.device_set)
+
+
+def _by_device(devs, stat: str) -> List[int]:
+    return [int((d.memory_stats() or {}).get(stat, 0)) for d in devs]
+
+
 def load_metric(name: str):
     path = HERE / "metrics" / f"{name}.py"
     spec = importlib.util.spec_from_file_location(
@@ -192,12 +210,14 @@ def _gap_numbers(theta: List[float], errbar: List[float]) -> Dict[str, float]:
 
 def check_answers(client: Client, t_start: float, exact, ref, epochs: int,
                   seed: int, delta: float, limits: Dict,
-                  control: bool = False) -> Dict[str, Dict]:
+                  control: bool = False,
+                  routes=FUSED_ROUTES) -> Dict[str, Dict]:
     """The numbers compared, each with its value and limit.
 
     With ``control`` the bfloat16 control's gaps (the reference on bfloat16
     operands, put in the program's place) are compared instead of the
     program's, so a run reads not correct exactly where the control would.
+    Answers of the fused ``routes`` are the ones ``ref`` recomputes.
     """
     from aqpbench.reference import Served
     from aqpbench.traffic import _rng
@@ -214,8 +234,13 @@ def check_answers(client: Client, t_start: float, exact, ref, epochs: int,
         else:
             misses += float(np.linalg.norm(theta - truth)) > it.epsilon
             verdicts += 1
-    fused = [r for r in window if r.resp.route.value in FUSED_ROUTES
+    fused = [r for r in window if r.resp.route.value in routes
              and not r.resp.shed]
+    others = sum(1 for r in window if r.resp.route.value in FUSED_ROUTES
+                 and r.resp.route.value not in routes)
+    if others:
+        log(f"{others} fused answers of the window on routes other than "
+            f"{list(routes)}: the reference does not follow them")
     # The sample: the answer with the most rows, then answers drawn from the
     # seed without replacement.
     rng = _rng(seed, 0xC4EC)
@@ -288,6 +313,11 @@ def run(args):
     if workload.get("loop") != "closed":
         raise RunError(f"workload loop {workload.get('loop')!r}: only "
                        f"closed loops are driven")
+    shards = int(config.get("data_shards", 1))
+    if not 1 <= shards <= int(cell["chips"]):
+        raise RunError(f"configuration {cell['config']!r} asks for "
+                       f"data_shards={shards}; the cell has {cell['chips']} "
+                       f"chips")
 
     import jax
 
@@ -331,9 +361,10 @@ def run(args):
     host = np.asarray(jax.device_get(values))[:, 0]
     exact = tpch.exact_answers(host, offsets)
     var = traffic.variants(workload, exact)
-    log(f"[{since_start():.3f} s] table: {len(host):,} rows, groups "
+    log(f"[{since_start():.3f} s] table: {int(offsets[-1]):,} rows, groups "
         f"{np.diff(offsets).tolist()}, {host.nbytes / 2**20:.1f} MiB on "
-        f"{dev.device_kind}; {len(var)} query variants")
+        f"{dev.device_kind} devices {_device_ids(values)}; {len(var)} query "
+        f"variants")
 
     def make_request(item) -> Request:
         return Request(query=Query(
@@ -347,12 +378,17 @@ def run(args):
     # ESTIMATE that the default picks off a TPU, whose float32 sums round
     # differently, so that the limits set on the chip apply to it.
     sess = AQPSession(data, B=int(contract["B"]), seed=seed % (2 ** 31 - 1),
-                      **({"use_kernel": True} if rehearsal else {}))
+                      **({"use_kernel": True} if rehearsal else {}),
+                      **({"data_shards": shards} if shards > 1 else {}))
     client = Client(sess, traffic.stream(var, seed), make_request, workload)
     client.run(answers=int(workload.get("warmup", {}).get("answers", 32)),
                until=time.perf_counter() + WARMUP_LIMIT_S)
     log(f"[{since_start():.3f} s] warmed with the cell's traffic "
         f"({len(client.done)} answers, {compiles[0]} compilations so far)")
+    if shards > 1 and sess.pool is not None:
+        log(f"pool values: {sess.pool.values.shape} on devices "
+            f"{_device_ids(sess.pool.values)}; bytes in use by device "
+            f"{_by_device(devs[:shards], 'bytes_in_use')}")
 
     # -- the measured window
     trace_dir = tempfile.mkdtemp(prefix="aqpbench-trace-") if args.trace \
@@ -375,10 +411,14 @@ def run(args):
                              if t_start <= r.done <= t_trace)
         jax.profiler.stop_trace()
         client.annotate = None
-        # The host's per-layer numbers come from the untraced rest.
+        # The host's per-layer numbers come from the untraced rest, which
+        # keeps its planned length however long the profiler takes to stop
+        # (tens of seconds for four chips' planes).
         t_resume = time.perf_counter()
         st_resume = sess.stats()
-    t_end = t_start + args.seconds
+        t_end = t_resume + args.seconds - (t_trace - t_start)
+    else:
+        t_end = t_start + args.seconds
     client.run(until=t_end)
     t_end = time.perf_counter()
     st1 = sess.stats()
@@ -397,9 +437,11 @@ def run(args):
         routes[r.resp.route.value] = routes.get(r.resp.route.value, 0) + 1
     log(f"routes in the window: {routes}; pool {st1.get('pool', {}).get('lanes')} "
         f"lanes, {st1.get('pool_rebuilds')} rebuilds, sample epoch "
-        f"{st1['sample_epoch']}")
-    peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
-               for d in devs[:int(cell["chips"])])
+        f"{st1['sample_epoch']}, slots held by shard "
+        f"{st1.get('pool', {}).get('shard_rows')}")
+    peaks = _by_device(devs[:int(cell["chips"])], "peak_bytes_in_use")
+    peak = max(peaks)
+    log(f"peak bytes in use by device {peaks}")
 
     record = {
         "cell": args.workload, "loop": workload["loop"],
@@ -422,6 +464,12 @@ def run(args):
         })
         ex = trace_reduce.extract(trace_reduce.find_xplane(trace_dir))
         record["trace"] = trace_reduce.reduce(ex)
+        # The metrics average over the device planes; each plane alone:
+        log("trace: busy seconds by device plane " + str({
+            plane: (trace_reduce.reduce({"host": ex["host"],
+                                         "device": {plane: evs}})
+                    or {}).get("busy_s")
+            for plane, evs in sorted(ex["device"].items())}))
         shutil.rmtree(trace_dir, ignore_errors=True)
         if record["trace"] is None:
             if not rehearsal:
@@ -436,23 +484,33 @@ def run(args):
 
     # -- correctness, after the program's state is freed
     # The reference follows the trajectory the session ran: its sample
-    # seed and the lane pool's recorded step parameters.
+    # seed, the lane pool's layout and its recorded step parameters.
     spec = getattr(sess.pool, "_spec", None) or {}
-    missing = [k for k in TRAJECTORY if k not in spec]
+    sharded = int(spec.get("data_shards", 1)) > 1
+    keys = TRAJECTORY_SHARDED if sharded else TRAJECTORY
+    missing = [k for k in keys if k not in spec]
     if missing:
         raise RunError(f"the session's lane pool records no {missing}")
-    traj = {k: int(spec[k]) for k in TRAJECTORY}
+    traj = {k: int(spec[k]) for k in keys}
     sess_seed = int(sess.seed)
     epochs = int(sess.stats()["sample_epoch"])
     del sess, data, values
     client.sess = None
     gc.collect()
     log(f"trajectory: session seed {sess_seed}, {traj}")
-    ref = reference.Reference(host, offsets, session_seed=sess_seed, **traj)
+    if sharded:
+        from aqpbench.reference_sharded import ShardedReference
+        ref = ShardedReference(host, offsets, session_seed=sess_seed, **traj)
+        log(f"sharded layout: {ref.rows_per_shard:,} rows a shard, slot "
+            f"capacity by group {ref.cap.tolist()}")
+    else:
+        ref = reference.Reference(host, offsets, session_seed=sess_seed,
+                                  **traj)
     log(f"[{since_start():.3f} s] program state freed; checking answers")
     checks = check_answers(client, t_start, exact, ref, epochs,
                            seed, float(contract["delta"]), workload["limits"],
-                           control=args.control)
+                           control=args.control,
+                           routes=("pool",) if sharded else FUSED_ROUTES)
     checks["unanswered"] = {"value": float(unanswered), "limit": 0.0,
                             "ok": unanswered == 0}
     correct = all(c["ok"] for c in checks.values())
@@ -479,6 +537,8 @@ def run(args):
             "idle_gaps": record["trace"]["idle_gaps"]}
     result["checks"] = {k: {"value": c["value"], "limit": c["limit"]}
                         for k, c in checks.items()}
+    log(f"host peak RSS {resource.getrusage(resource.RUSAGE_SELF).ru_maxrss}"
+        f" KiB")
     for k, c in checks.items():
         log(f"check {k} = {c['value']!r} limit {c['limit']!r} "
             f"{'ok' if c['ok'] else 'FAIL'}")

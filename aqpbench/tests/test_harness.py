@@ -13,7 +13,8 @@ interpreted) over a table of a few hundred thousand rows, for both cells:
   each fault a one-chip serving cell can have: a step that returns its
   state unchanged, half of each lane's sample left out of the estimate,
   and an answer altered where the step produces it.  (These cells hold no
-  exchange between chips.)
+  exchange between chips; ``test_sharded.py`` plants that fault, and the
+  others, in a row-sharded cell on four host devices.)
 * with the answer contract weakened inside the program, ``correct`` comes
   out false: lanes that stop after two ticks (at the init design's n_min
   and n_max), and a stopping test run at twice the requested epsilon.

@@ -16,7 +16,9 @@ The columns follow the TPC-H specification (v3, clause 4.2.3):
 The price does not depend on the group column, so the group-sorted table
 (the layout ``GroupedData`` serves) is the price column in draw order cut
 into runs of the per-group counts.  Everything is made in one jitted call;
-only the counts come back to the host.
+only the counts come back to the host.  A configuration with
+``"data_shards": S`` (S > 1) gets the table row-sharded over the first S
+devices, made there shard by shard, so that no device holds it whole.
 """
 from __future__ import annotations
 
@@ -26,6 +28,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 # Days from STARTDATE (1992-01-01).
 ORDERDATE_MAX = 2405          # ENDDATE (1998-12-31) - 151 days
@@ -39,9 +42,9 @@ def seed_key(seed: int):
     return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("rows", "scale_factor", "group_by"))
-def _make(key, *, rows: int, scale_factor: int, group_by: str):
+def _draw(key, rows: int, scale_factor: int, group_by: str):
+    """``(price (rows,) float32, gid (rows,) int32, m)``: the rows in draw
+    order and the group of each."""
     k_part, k_qty, k_g1, k_g2, k_g3 = jax.random.split(key, 5)
     partkey = jax.random.randint(k_part, (rows,), 1,
                                  scale_factor * 200_000 + 1, jnp.int32)
@@ -67,18 +70,54 @@ def _make(key, *, rows: int, scale_factor: int, group_by: str):
         m = 7
     else:
         raise ValueError(f"unknown group column {group_by!r}")
-    counts = jnp.stack([jnp.sum(gid == g, dtype=jnp.int32)
-                        for g in range(m)])
-    return price[:, None], counts
+    return price, gid, m
+
+
+def _counts(gid, m: int):
+    return jnp.stack([jnp.sum(gid == g, dtype=jnp.int32) for g in range(m)])
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("rows", "scale_factor", "group_by"))
+def _make(key, *, rows: int, scale_factor: int, group_by: str):
+    price, gid, m = _draw(key, rows, scale_factor, group_by)
+    return price[:, None], _counts(gid, m)
+
+
+def _make_padded(key, *, rows: int, padded: int, scale_factor: int,
+                 group_by: str):
+    """``_make`` drawn over ``padded >= rows`` rows, the ``padded - rows``
+    rows at the end zero and in no group."""
+    price, gid, m = _draw(key, padded, scale_factor, group_by)
+    valid = jnp.arange(padded) < rows
+    price = jnp.where(valid, price, jnp.float32(0.0))
+    return price[:, None], _counts(jnp.where(valid, gid, m), m)
 
 
 def make_lineitem(config: Dict, seed: int, rows: int | None = None
                   ) -> Tuple[object, np.ndarray]:
-    """``(values (N, 1) float32 on the device, offsets (m + 1,) int64)``."""
+    """``(values (N', 1) float32 on the device, offsets (m + 1,) int64)``.
+
+    With ``"data_shards": S`` above 1 the table is made row-sharded over
+    the first S devices (a 1-D ``("data",)`` mesh), padded with zero rows
+    to ``N' = S * ceil(N / S)``, whole shards; the offsets cover the N rows
+    of the groups alone.  Otherwise ``N' = N``, on the default device.
+    """
     n = int(rows if rows is not None else config["rows"])
-    values, counts = _make(seed_key(seed), rows=n,
-                           scale_factor=int(config["scale_factor"]),
-                           group_by=config["group_by"])
+    shards = int(config.get("data_shards", 1))
+    kw = dict(scale_factor=int(config["scale_factor"]),
+              group_by=config["group_by"])
+    if shards == 1:
+        values, counts = _make(seed_key(seed), rows=n, **kw)
+    else:
+        mesh = Mesh(np.asarray(jax.devices()[:shards]), ("data",))
+        make = jax.jit(
+            _make_padded,
+            static_argnames=("rows", "padded", "scale_factor", "group_by"),
+            out_shardings=(NamedSharding(mesh, PartitionSpec("data", None)),
+                           NamedSharding(mesh, PartitionSpec())))
+        values, counts = make(seed_key(seed), rows=n,
+                              padded=shards * -(-n // shards), **kw)
     counts = np.asarray(jax.device_get(counts), np.int64)
     offsets = np.concatenate([[0], np.cumsum(counts)])
     return values, offsets
