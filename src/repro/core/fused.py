@@ -1180,6 +1180,13 @@ def make_sharded_step(mesh, *, num_ticks: int = 1, **statics):
                               tuple(sorted(statics.items())))
 
 
+def sharded_psum_bytes(q: int, m: int, B: int) -> int:
+    """Bytes each device adds to one tick's moment ``psum``s in the sharded
+    step of ``q`` lanes over ``m`` groups: the float32 replicate sums ``M_s
+    (q, m, B, 3)`` and plain sums ``Mp_s (q, m, 3)``."""
+    return 4 * q * m * 3 * (B + 1)
+
+
 def sharded_step_cache_size() -> int:
     """Entries resident in the :func:`make_sharded_step` memo LRU."""
     return _make_sharded_step.cache_info().currsize

@@ -14,7 +14,7 @@ job both use it).
 """
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -51,6 +51,19 @@ def make_data_mesh(num_devices: Optional[int] = None) -> Mesh:
     return Mesh(np.asarray(devs[:n]), (DATA_AXIS,))
 
 
+def table_mesh(values, num_devices: int) -> Mesh:
+    """The data mesh of a table held in ``num_devices`` row shards: the one
+    ``values`` is already split along rows over, if it is such a mesh, so
+    that placing the table moves nothing; else :func:`make_data_mesh`'s
+    (whose device order may differ: on a v5e 2x2 it is [0, 1, 3, 2])."""
+    sh = getattr(values, "sharding", None)
+    if (isinstance(sh, NamedSharding) and sh.mesh.axis_names == (DATA_AXIS,)
+            and sh.mesh.devices.size == int(num_devices)
+            and tuple(sh.spec)[:1] == (DATA_AXIS,)):
+        return sh.mesh
+    return make_data_mesh(num_devices)
+
+
 def data_sharding(mesh: Mesh, ndim: int = 1, axis: int = 0) -> NamedSharding:
     """Sharding with dimension ``axis`` split over the data axis, the rest
     replicated."""
@@ -60,9 +73,46 @@ def data_sharding(mesh: Mesh, ndim: int = 1, axis: int = 0) -> NamedSharding:
 
 
 def put_sharded(mesh: Mesh, x, axis: int = 0) -> Array:
-    """``device_put`` with dimension ``axis`` sharded over the data axis."""
-    x = jnp.asarray(x)
+    """``device_put`` with dimension ``axis`` sharded over the data axis.
+
+    Host input goes straight to the sharding, so each device receives its
+    own slice alone; a device array moves shard by shard.  Neither is
+    first committed whole to one device."""
+    if not isinstance(x, jax.Array):
+        x = np.asarray(x)
     return jax.device_put(x, data_sharding(mesh, x.ndim, axis))
+
+
+def put_row_blocks(mesh: Mesh, values, rows: int) -> Tuple[Array, int]:
+    """``(table, host_bytes)``: the 2-D table row-sharded over the mesh in
+    whole blocks of ``rows / mesh size`` rows, zero-padded to ``rows``.
+
+    A device array that already holds ``rows`` rows split along rows over
+    the mesh's devices is taken as it is (a ``device_put`` to the mesh's
+    sharding: nothing moves, or each shard moves between two devices where
+    the mesh orders them differently) and ``host_bytes`` is 0.  Anything
+    else is padded on the host and sent slice by slice (``host_bytes`` is
+    the padded table's size).  No device ever holds more than its block.
+    """
+    sharding = data_sharding(mesh, 2)
+    if (isinstance(values, jax.Array) and values.ndim == 2
+            and values.shape[0] == rows
+            and values.sharding.device_set == set(mesh.devices.flat)
+            and values.sharding.shard_shape(values.shape)
+            == (rows // mesh.devices.size, values.shape[1])):
+        return jax.device_put(values, sharding), 0
+    v = pad_rows(values, rows)
+    return jax.device_put(v, sharding), int(v.nbytes)
+
+
+def pad_rows(values, rows: int) -> np.ndarray:
+    """The table on the host, 2-D, zero-padded to ``rows`` rows."""
+    v = np.asarray(values)
+    if v.ndim == 1:
+        v = v[:, None]
+    if len(v) < rows:
+        v = np.pad(v, ((0, rows - len(v)), (0, 0)))
+    return v
 
 
 def put_replicated(mesh: Mesh, x) -> Array:
@@ -83,5 +133,5 @@ def shard_dataset(mesh: Mesh, gid: np.ndarray, x: np.ndarray):
     pad = per * mesh.devices.size - n
     gid_p = np.pad(gid, (0, pad), constant_values=-1)   # -1 = invalid row
     x_p = np.pad(x, (0, pad))
-    return (jax.device_put(jnp.asarray(gid_p, jnp.int32), sh),
-            jax.device_put(jnp.asarray(x_p, jnp.float32), sh))
+    return (jax.device_put(gid_p.astype(np.int32), sh),
+            jax.device_put(x_p.astype(np.float32), sh))

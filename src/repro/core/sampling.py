@@ -407,16 +407,6 @@ class ShardLayout:
             alloc=alloc.astype(np.int32), cap_groups=cap_groups.astype(np.int32))
 
     # -- host-side helpers ---------------------------------------------------
-    def pad_values(self, values) -> np.ndarray:
-        """Values padded with zero rows to ``S * rows_per_shard`` (2-D)."""
-        v = np.asarray(values)
-        if v.ndim == 1:
-            v = v[:, None]
-        total = self.num_shards * self.rows_per_shard
-        if len(v) < total:
-            v = np.pad(v, ((0, total - len(v)), (0, 0)))
-        return v
-
     def shard_rows(self, filled) -> np.ndarray:
         """(S,) resident slots per shard at per-group watermarks ``filled``
         (m,) -- the per-shard dispatch accounting the pool's stats report."""

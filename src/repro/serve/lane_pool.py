@@ -76,7 +76,7 @@ from ..core.fused import (LaneParams, LaneState, ShardSpec, as_columns,
                           make_lane_params, make_shard_spec,
                           make_sharded_lane_params, make_sharded_step,
                           resolve_ext_cap, resolve_seg_window, scoped,
-                          sharded_step_cache_size)
+                          sharded_psum_bytes, sharded_step_cache_size)
 from ..core import estimators
 from ..core import sanitize
 from ..core.sampling import (GroupedData, ShardLayout, counter_slot_table,
@@ -386,15 +386,24 @@ class LanePool:
                 self._mesh = None
             else:
                 self._mesh = mesh if mesh is not None else (
-                    core_mesh.make_data_mesh(self.data_shards))
+                    core_mesh.table_mesh(data.values, self.data_shards))
                 if self._mesh.devices.size != self.data_shards:
                     raise ValueError(
                         f"mesh has {self._mesh.devices.size} devices; pool "
                         f"wants data_shards={self.data_shards}")
+            rows = self.data_shards * self._layout.rows_per_shard
             with self.recorder.phase("table_layout"):
-                padded = self._layout.pad_values(np.asarray(data.values))
-                self._values = (jnp.asarray(padded) if self._mesh is None
-                                else core_mesh.put_sharded(self._mesh, padded))
+                if self._mesh is None:
+                    padded = core_mesh.pad_rows(data.values, rows)
+                    self._values = jnp.asarray(padded)
+                    self.table_host_bytes = int(padded.nbytes)
+                else:
+                    # The session's table, made row-sharded in the layout's
+                    # blocks, is taken as it is; no device ever holds more
+                    # than its own block.
+                    self._values, self.table_host_bytes = \
+                        core_mesh.put_row_blocks(self._mesh, data.values,
+                                                 rows)
             sspec = make_shard_spec(self._layout)
             if self._mesh is not None:
                 sspec = ShardSpec(
@@ -418,6 +427,7 @@ class LanePool:
             # relayouts the table.
             with self.recorder.phase("table_layout"):
                 self._values = as_columns(data.values)
+            self.table_host_bytes = 0
             self._spec = dict(
                 est_name=None, B=B, n_min=n_min, n_max=n_max,
                 l=int(l if l is not None else min(m + 2, 12)), tau=1e-3,
@@ -425,6 +435,19 @@ class LanePool:
                 metric=metric, growth_cap=growth_cap,
                 ext_cap=resolve_ext_cap(n_cap, n_max, ext_cap), adaptive=True,
                 use_kernel=use_kernel, gate_gather=gate_gather)
+        # Bytes of the table on each device, and bytes each chip adds to
+        # the per-tick moment psums (``fused.sharded_psum_bytes``) summed
+        # over ticks; 0 off the mesh.
+        self.table_bytes_by_device: Dict[int, int] = {}
+        for col in jax.tree_util.tree_leaves(self._values):
+            for sh in col.addressable_shards:
+                self.table_bytes_by_device[sh.device.id] = (
+                    self.table_bytes_by_device.get(sh.device.id, 0)
+                    + int(sh.data.nbytes))
+        self._psum_tick_bytes = (
+            sharded_psum_bytes(self.tier_lanes, m, B)
+            if self._mesh is not None else 0)
+        self.psum_bytes = 0
         # Steady-state recompile sentinel (misslint ML30x at runtime): a
         # snapshot of the resident-program cache, re-armed whenever a NEW
         # program config legitimately enters (retuned cadence, a fresh
@@ -1155,6 +1178,7 @@ class LanePool:
                 step, args, kw = self._tier_program(tier)
                 tier.state = step(*args, **kw)
                 self.dispatches += 1
+                self.psum_bytes += self._psum_tick_bytes * self.ticks_per_sync
                 self.lane_ticks_busy += busy * self.ticks_per_sync
                 self._active_frac_sum += busy / self.tier_lanes
                 ran = True
@@ -1343,4 +1367,9 @@ class LanePool:
             # The process-wide make_sharded_step memo LRU (bounded; every
             # pool shares it, so this is global occupancy, not per-pool).
             "sharded_step_cache": sharded_step_cache_size(),
+            # Where the table sits: resident bytes by device id, and the
+            # bytes the pool sent from the host to place it.
+            "table_bytes_by_device": dict(self.table_bytes_by_device),
+            "table_host_bytes": self.table_host_bytes,
+            "psum_bytes": self.psum_bytes,
         }
