@@ -424,9 +424,8 @@ def scoped(name: str):
     return wrap
 
 
-@jax.jit
-def as_columns(values: Array) -> Tuple[Array, ...]:
-    """The table as the single-shard step reads it: ``c`` 1-D columns.
+def as_columns(values: Array, sharding=None) -> Tuple[Array, ...]:
+    """The table as every step reads it: ``c`` 1-D columns.
 
     A row gather from a 2-D ``(N, c)`` table makes XLA relayout the whole
     table into the gather's 1-D form on every use -- inside the tick, once
@@ -434,8 +433,21 @@ def as_columns(values: Array) -> Tuple[Array, ...]:
     tuple, not one c-major vector: ``col * N + row`` overflows int32 at
     scale.  Jitted, so that the columns are written in one program, with
     no whole-table intermediate between the slice and the relayout.
+
+    ``sharding`` pins where each column lies; for a row-sharded table, its
+    1-D row sharding over the same mesh, so that every device writes its
+    own block of each column from its own block of rows and holds no more.
     """
-    return tuple(values[:, j] for j in range(values.shape[1]))
+    return _columns_program(sharding)(values)
+
+
+@functools.lru_cache(maxsize=16)
+def _columns_program(sharding):
+    def columns(values):
+        return tuple(values[:, j] for j in range(values.shape[1]))
+    if sharding is None:
+        return jax.jit(columns)
+    return jax.jit(columns, out_shardings=sharding)
 
 
 def _gather_rows(cols: Tuple[Array, ...], idx: Array) -> Array:
@@ -884,7 +896,7 @@ def resolve_seg_window(n_cap: int, n_max: int, data_shards: int,
 
 
 def _sharded_step_body(
-    values: Array,      # (N, c) global | (R, c) per-device slice (mesh)
+    cols: Tuple[Array, ...],  # c x (N,) global | (R,) per-device slice (mesh)
     s: LaneState,
     p: LaneParams,      # slot_idx (S, m, cap_s) | (1, m, cap_s) local slice
     spec: ShardSpec,
@@ -920,6 +932,10 @@ def _sharded_step_body(
     3)``/``(q, m, 3)`` moment psum: the growth clamp folds the replicated
     alloc stack locally on every device, and everything else -- FIT,
     PREDICT, TEST, the whole LaneState except ``buf`` -- is replicated.
+
+    ``cols`` is the table (each device's row block of it under the mesh)
+    as :func:`as_columns` gives it, so the segment gathers read the
+    columns and no tick relayouts a shard.
     """
     est = get_estimator(est_name) if est_name is not None else None
     cap_s = n_cap // data_shards
@@ -1027,7 +1043,7 @@ def _sharded_step_body(
                     valid = slots < h_l[:, None]
                     clipped = jnp.minimum(slots, cap_s - 1)
                     gidx = jnp.take_along_axis(table_sm, clipped, axis=1)
-                    new_rows = values[gidx]                    # (m, W, c)
+                    new_rows = _gather_rows(cols, gidx)        # (m, W, c)
                     tgt = jnp.where(valid, slots, cap_s)       # OOB -> drop
                     return buf_l.at[jnp.arange(m)[:, None], tgt].set(
                         new_rows, mode="drop")
@@ -1163,10 +1179,12 @@ def make_sharded_step(mesh, *, num_ticks: int = 1, **statics):
 
     ``statics`` are the :data:`_SHARD_STEP_STATICS` (``seg_window`` already
     resolved via :func:`resolve_seg_window`).  Per device and tick: its
-    values slice, its buffer segment, its slot table, and ONE collective
-    (the moment-sums ``psum``; the growth clamp is local).  Returns
-    ``step(values, state, params, shard_spec) -> state`` preserving input
-    shardings; every LaneState leaf except ``buf`` stays replicated.
+    row block of each column, its buffer segment, its slot table, and ONE
+    collective (the moment-sums ``psum``; the growth clamp is local).
+    Returns ``step(cols, state, params, shard_spec) -> state`` preserving
+    input shardings, where ``cols`` is :func:`as_columns` of the
+    row-sharded table, each column row-sharded over ``mesh``; every
+    LaneState leaf except ``buf`` stays replicated.
 
     Memoized on ``(mesh, num_ticks, statics)``: callers that rebuild pools
     (benchmarks, serving rebuilds) share ONE jitted program instead of
@@ -1213,16 +1231,16 @@ def _make_sharded_step(mesh, num_ticks, statics_items):
     # growth clamp (and its own shard's table via axis_index).
     sp_specs = ShardSpec(alloc=PS(), cap_groups=PS())
 
-    def body(values, state, params, sspec):
+    def body(cols, state, params, sspec):
         def one(st):
-            return _sharded_step_body(values, st, params, sspec, **spec)
+            return _sharded_step_body(cols, st, params, sspec, **spec)
         if num_ticks == 1:
             return one(state)
         return jax.lax.fori_loop(0, num_ticks, lambda _, st: one(st), state)
 
     sm = jax.shard_map(
         body, mesh=mesh,
-        in_specs=(PS("data", None), st_specs, pr_specs, sp_specs),
+        in_specs=(PS("data"), st_specs, pr_specs, sp_specs),
         out_specs=st_specs, check_vma=False)
     return jax.jit(sm)
 
@@ -1238,7 +1256,7 @@ _STEP_STATICS = (
          static_argnames=_STEP_STATICS + ("num_ticks", "data_shards",
                                           "seg_window", "seg_cap"))
 def fused_step(
-    values: "Tuple[Array, ...] | Array",
+    values: Tuple[Array, ...],
     offsets: Array,
     state: LaneState,
     params: LaneParams,
@@ -1275,8 +1293,7 @@ def fused_step(
     ``values`` is the table as :func:`as_columns` gives it -- a tuple of
     ``c`` 1-D ``(N,)`` columns, built once per table, never per call -- so
     the row gathers read the columns and the compiled step holds no
-    whole-table relayout.  The sharded body (``data_shards > 1``) takes its
-    padded ``(N_pad, c)`` table instead.
+    whole-table relayout.
 
     ``data_shards > 1`` runs the SHARDED body (phase G) on one device --
     the solo-emulation reference whose answers the mesh step
@@ -1299,10 +1316,9 @@ def fused_step(
     """
     if seg_window is not None and data_shards == 1:
         raise ValueError("seg_window applies to the sharded step only")
-    if (data_shards == 1) != isinstance(values, tuple):
-        raise TypeError(
-            "the single-shard step takes the table as as_columns(values); "
-            "the sharded step takes its padded (N_pad, c) table")
+    if not isinstance(values, tuple):
+        raise TypeError("values must be as_columns(values): the step takes "
+                        "the table as a tuple of 1-D columns")
     if seg_cap is not None:
         if data_shards > 1:
             raise ValueError("seg_cap (grouped blocks) is single-shard only")
@@ -1397,7 +1413,8 @@ def _sharded_lanes_closed(
     use_kernel: bool,
     data_shards: int,
 ) -> FusedResult:
-    """Closed-loop driver over :func:`_sharded_step_body` (solo emulation)."""
+    """The closed loop over :func:`_sharded_step_body` (solo emulation) on
+    the ``(N, c)`` table, whose columns it builds once, outside the loop."""
     m = shard_spec.cap_groups.shape[0]
     boot_base = jax.vmap(lane_boot_seed)(keys)
     q = epsilons.shape[0]
@@ -1419,9 +1436,10 @@ def _sharded_lanes_closed(
         max_iters=max_iters, n_cap=n_cap, metric=metric,
         growth_cap=growth_cap, seg_window=seg_window, use_kernel=use_kernel,
         data_shards=data_shards, axis_name=None)
+    cols = as_columns(values)     # once per call, outside the loop
     state = jax.lax.while_loop(
         lambda st: jnp.any(lane_active(st, max_iters)),
-        lambda st: _sharded_step_body(values, st, params, shard_spec, **spec),
+        lambda st: _sharded_step_body(cols, st, params, shard_spec, **spec),
         state0)
     return lanes_result(state)
 

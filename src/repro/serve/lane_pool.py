@@ -60,7 +60,7 @@ import dataclasses
 import time
 from collections import deque
 from functools import partial
-from typing import Deque, Dict, List, Optional
+from typing import Deque, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -397,13 +397,17 @@ class LanePool:
                     padded = core_mesh.pad_rows(data.values, rows)
                     self._values = jnp.asarray(padded)
                     self.table_host_bytes = int(padded.nbytes)
+                    self._cols = as_columns(self._values)
                 else:
                     # The session's table, made row-sharded in the layout's
-                    # blocks, is taken as it is; no device ever holds more
-                    # than its own block.
+                    # blocks, is taken as it is, and each device writes its
+                    # own block of each column from it: no device ever
+                    # holds more than its own blocks.
                     self._values, self.table_host_bytes = \
                         core_mesh.put_row_blocks(self._mesh, data.values,
                                                  rows)
+                    self._cols = as_columns(
+                        self._values, core_mesh.data_sharding(self._mesh))
             sspec = make_shard_spec(self._layout)
             if self._mesh is not None:
                 sspec = ShardSpec(
@@ -426,7 +430,7 @@ class LanePool:
             # Built once per pool: the step reads 1-D columns, so no tick
             # relayouts the table.
             with self.recorder.phase("table_layout"):
-                self._values = as_columns(data.values)
+                self._values = self._cols = as_columns(data.values)
             self.table_host_bytes = 0
             self._spec = dict(
                 est_name=None, B=B, n_min=n_min, n_max=n_max,
@@ -435,11 +439,14 @@ class LanePool:
                 metric=metric, growth_cap=growth_cap,
                 ext_cap=resolve_ext_cap(n_cap, n_max, ext_cap), adaptive=True,
                 use_kernel=use_kernel, gate_gather=gate_gather)
-        # Bytes of the table on each device, and bytes each chip adds to
-        # the per-tick moment psums (``fused.sharded_psum_bytes``) summed
-        # over ticks; 0 off the mesh.
+        # Bytes the pool holds of the table on each device (the table and
+        # its columns, where they are not the same arrays), and bytes each
+        # chip adds to the per-tick moment psums
+        # (``fused.sharded_psum_bytes``) summed over ticks; 0 off the mesh.
         self.table_bytes_by_device: Dict[int, int] = {}
-        for col in jax.tree_util.tree_leaves(self._values):
+        held = {id(a): a for a in jax.tree_util.tree_leaves(
+            (self._values, self._cols))}
+        for col in held.values():
             for sh in col.addressable_shards:
                 self.table_bytes_by_device[sh.device.id] = (
                     self.table_bytes_by_device.get(sh.device.id, 0)
@@ -1059,12 +1066,19 @@ class LanePool:
 
     @property
     def values(self) -> "tuple | Array":
-        """The resident table as the tiers and blocks read it, built once
-        in ``__init__`` (phase ``table_layout``): a tuple of ``c`` 1-D
-        ``(N,)`` columns (:func:`~repro.core.fused.as_columns`) on one
-        device; the ``(N_pad, c)`` table padded to the shard layout (and
-        row-sharded over the mesh) when ``data_shards > 1``."""
+        """The resident table, placed once in ``__init__`` (phase
+        ``table_layout``): :attr:`columns` on one device; the ``(N_pad,
+        c)`` table padded to the shard layout (and row-sharded over the
+        mesh) when ``data_shards > 1``."""
         return self._values
+
+    @property
+    def columns(self) -> Tuple[Array, ...]:
+        """The table as the tiers and blocks read it, built once in
+        ``__init__`` (phase ``table_layout``): a tuple of ``c`` 1-D columns
+        (:func:`~repro.core.fused.as_columns`), each row-sharded over the
+        mesh like :attr:`values` when the pool has one."""
+        return self._cols
 
     @property
     def ticks_per_sync(self) -> int:
@@ -1098,9 +1112,9 @@ class LanePool:
                 step = make_sharded_step(
                     self._mesh, num_ticks=self.ticks_per_sync, **self._spec)
                 self._step_cache[self.ticks_per_sync] = step
-            return (step, (self._values, tier.state, tier.params,
+            return (step, (self._cols, tier.state, tier.params,
                            self._shard_spec), {})
-        args = (self._values, self._offsets, tier.state, tier.params)
+        args = (self._cols, self._offsets, tier.state, tier.params)
         if self._layout is not None:
             # Single-device run of the SAME shard layout (mesh=False): the
             # sequential segment fold the mesh psum reproduces.  seg_window
@@ -1112,7 +1126,7 @@ class LanePool:
 
     def _block_program(self, state: LaneState, params: LaneParams):
         """``(program, args, kwargs)`` of one grouped block dispatch."""
-        return fused_step, (self._values, self._goffsets, state, params), \
+        return fused_step, (self._cols, self._goffsets, state, params), \
             dict(num_ticks=self.ticks_per_sync, seg_cap=self._gseg_cap,
                  **self._spec)
 

@@ -271,3 +271,17 @@ def test_mesh_pool_bit_equal_to_solo_pool(data):
                 == np.asarray(b.error, np.float32).tobytes())
         assert (np.asarray(a.theta, np.float32).ravel().tobytes()
                 == np.asarray(b.theta, np.float32).ravel().tobytes())
+
+
+@pytest.mark.parametrize("S", [1, 2])
+def test_step_refuses_a_2d_table(data, S):
+    """Every step takes the table as ``as_columns(...)``, the sharded one
+    (``data_shards > 1``) as well: the pool hands it the columns, and the
+    ``(N, c)`` table raises ``TypeError``."""
+    from repro.serve.lane_pool import LanePool
+    pool = LanePool(data, lanes=2, tiers=1, data_shards=S, mesh=False,
+                    seed=0, **SPEC)
+    step, args, kw = pool._tier_program(pool._tiers[0])
+    assert isinstance(args[0], tuple) and args[0] is pool.columns
+    with pytest.raises(TypeError, match="as_columns"):
+        step(jnp.asarray(data.values), *args[1:], **kw)

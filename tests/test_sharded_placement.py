@@ -12,6 +12,10 @@ script), so that the test process keeps its one device:
 * a host table, or one held otherwise on the devices, is placed so that
   each device receives its own block alone, and a mesh given in another
   device order gets each block moved to its place;
+* from every placement, ``LanePool.values`` stays the 2-D row-sharded
+  table, and the step's 1-D columns lie on the table's own mesh, each
+  device holding its own block of each column, the same rows as its block
+  of the table; ``table_bytes_by_device`` counts both;
 * ``psum_bytes`` grows by the moment ``psum``'s payload, as the compiled
   step's ``all-reduce``s give it, at every tick;
 * the mesh pool's answers are bit-equal to those of the ``mesh=False``
@@ -67,6 +71,36 @@ def _answers(results):
             for r in results]
 
 
+def _held(pool) -> dict:
+    """What the pool keeps of the table: the 2-D table and, for each
+    column the step reads, whether it is split in the table's row blocks
+    over the table's own mesh and holds the table's rows there."""
+    from repro.core import mesh as core_mesh
+
+    table = pool.values
+    mesh = table.sharding.mesh
+    blocks = {s.device: s for s in table.addressable_shards}
+    cols = []
+    for j, col in enumerate(pool.columns):
+        shards = col.addressable_shards
+        cols.append({
+            "shape": list(col.shape),
+            "row_sharded": (
+                col.sharding.mesh == mesh
+                and col.sharding.is_equivalent_to(
+                    core_mesh.data_sharding(mesh), 1)),
+            "devices": len({s.device for s in shards}),
+            "blocks_of_table": all(
+                s.index == blocks[s.device].index[:1]
+                and np.array_equal(np.asarray(s.data),
+                                   np.asarray(blocks[s.device].data)[:, j])
+                for s in shards)})
+    return {"shape": list(table.shape),
+            "spec": [d for d in table.sharding.spec],
+            "columns": cols,
+            "by_device": pool.stats()["table_bytes_by_device"]}
+
+
 def _four_devices() -> dict:
     """The checks on four host devices; a JSON-able summary."""
     import jax
@@ -114,6 +148,7 @@ def _four_devices() -> dict:
         "hlo_bytes": _hlo_all_reduce_bytes(
             pool.lowered_tick().compile().as_text())}
     assert [r.qid for r in pool.drain()] == [qid]
+    out["held"] = {"taken": _held(pool)}
     before = pool.stats()["psum_bytes"]
     ticks0 = pool.ticks
     out["answers_taken"] = _answers(_drain(pool, specs, keys))
@@ -126,6 +161,7 @@ def _four_devices() -> dict:
     out["hosted"] = {"host_bytes": st["table_host_bytes"],
                      "by_device": st["table_bytes_by_device"],
                      "shape": list(pool.values.shape)}
+    out["held"]["hosted"] = _held(pool)
     out["answers_hosted"] = _answers(_drain(pool, specs, keys))
 
     # Held otherwise on the same devices (here: whole on each), the table
@@ -137,6 +173,7 @@ def _four_devices() -> dict:
     st = pool.stats()
     out["resharded"] = {"host_bytes": st["table_host_bytes"],
                         "by_device": st["table_bytes_by_device"]}
+    out["held"]["resharded"] = _held(pool)
     out["answers_resharded"] = _answers(_drain(pool, specs, keys))
     # A table split over a mesh that orders the devices otherwise than
     # make_data_mesh does (as on a v5e 2x2) is still taken as it is.
@@ -151,6 +188,7 @@ def _four_devices() -> dict:
              for s in reordered.addressable_shards}
             == {s.device.id: s.data.unsafe_buffer_pointer()
                 for s in pool.values.addressable_shards})}
+    out["held"]["reordered"] = _held(pool)
     out["answers_reordered"] = _answers(_drain(pool, specs, keys))
     # A mesh that orders the devices the other way round: each block moves
     # to the device that holds its place in the mesh.
@@ -165,6 +203,11 @@ def _four_devices() -> dict:
                            blocks[flipped.devices.tolist().index(s.device)
                                   * r:][:r])
             for s in pool.values.addressable_shards)}
+    out["held"]["flipped"] = _held(pool)
+    # Two columns: each device holds its block of the table and of each.
+    two = np.concatenate([host, 2.0 * host], axis=1)
+    out["held"]["two_columns"] = _held(LanePool(GroupedData(two, offsets),
+                                                **kw))
 
     pool = LanePool(hosted, mesh=False, **kw)
     out["one_device"] = {"psum_bytes": pool.stats()["psum_bytes"]}
@@ -188,7 +231,8 @@ def test_sharded_table_is_taken_as_it_is(four):
     assert taken["host_bytes"] == 0
     assert taken["same_buffers"]
     block = four["rows_per_shard"] * 4
-    assert taken["by_device"] == {str(d): block for d in range(SHARDS)}
+    # Its block of the table and of the one column the step reads.
+    assert taken["by_device"] == {str(d): 2 * block for d in range(SHARDS)}
 
 
 def test_table_on_another_device_order_is_taken_as_it_is(four):
@@ -200,16 +244,37 @@ def test_host_table_is_sent_block_by_block(four):
     block = four["rows_per_shard"] * 4
     assert hosted["shape"] == [SHARDS * four["rows_per_shard"], 1]
     assert hosted["host_bytes"] == SHARDS * block
-    # Each device holds its own block, and no device more.
-    assert hosted["by_device"] == {str(d): block for d in range(SHARDS)}
+    # Each device holds its own block of the table and of the column, and
+    # no device more.
+    assert hosted["by_device"] == {str(d): 2 * block for d in range(SHARDS)}
 
 
 def test_table_held_otherwise_is_placed_block_by_block(four):
     block = four["rows_per_shard"] * 4
     assert four["resharded"] == {
         "host_bytes": SHARDS * block,
-        "by_device": {str(d): block for d in range(SHARDS)}}
+        "by_device": {str(d): 2 * block for d in range(SHARDS)}}
     assert four["flipped"] == {"host_bytes": 0, "blocks_in_place": True}
+
+
+@pytest.mark.parametrize("placement", ["taken", "hosted", "resharded",
+                                       "reordered", "flipped",
+                                       "two_columns"])
+def test_columns_are_row_blocks_of_the_table(four, placement):
+    """The step reads 1-D columns; ``values`` is still the 2-D table.  Each
+    column lies on the table's own mesh, one block a device, and a device's
+    block of it is its block of the table's column."""
+    held = four["held"][placement]
+    r = four["rows_per_shard"]
+    c = 2 if placement == "two_columns" else 1
+    assert held["shape"] == [SHARDS * r, c]
+    assert held["spec"] == ["data", None]
+    assert held["columns"] == [{"shape": [SHARDS * r], "row_sharded": True,
+                                "devices": SHARDS, "blocks_of_table": True}
+                               ] * c
+    # table_bytes_by_device counts the table's block and each column's.
+    assert held["by_device"] == {str(d): 2 * c * r * 4
+                                 for d in range(SHARDS)}
 
 
 def test_psum_bytes_grow_by_the_payload_per_tick(four):

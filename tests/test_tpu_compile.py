@@ -9,8 +9,10 @@ slots, B=300), and check that the program holds the Pallas kernel
 only the worker that runs this file loads the TPU compiler.
 
 The lane pool's tier and grouped block steps are compiled the same way at
-the row count of TPC-H lineitem SF 10, to check that the table reaches the
-row gathers as it is passed in, with no whole-table relayout in the tick.
+the row count of TPC-H lineitem SF 10, and its row-sharded tier step for
+the four chips of the described ``v5e:2x2`` at SF 300's (one 449,997,273-row
+block of each column a chip), to check that the table reaches the row
+gathers as it is passed in, with no whole-table relayout in the tick.
 """
 import re
 
@@ -18,9 +20,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.aqp.query import Query
+from repro.core import mesh as core_mesh
 from repro.core.sampling import GroupedData
 from repro.kernels.poisson_bootstrap import kernel as pb_kernel
 from repro.kernels.poisson_bootstrap import ops as pb_ops
@@ -32,13 +36,18 @@ WIDTH = 1 << 16     # the pool's n_cap: slots per (lane, group)
 SF10_ROWS = 59_986_052
 # L_RETURNFLAG's groups A, N, R at SF 10 (24.7%, 50.6%, 24.7%).
 SF10_OFFSETS = [0, 14_816_555, 45_169_497, SF10_ROWS]
+# Lineitem SF 300 by L_RETURNFLAG (1,799,989,091 rows), padded to four
+# row shards of the same size.
+SF300_OFFSETS = [0, 444_191_988, 1_355_798_201, 1_799_989_091]
+SF300_SHARDS = 4
+SF300_SHARD_ROWS = 449_997_273
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One chip of a described v5e:2x2, with the persistent compilation
-    cache off: a program compiled for a described chip is written to the
-    cache but cannot be read back without one."""
+def topo():
+    """A described v5e:2x2, with the persistent compilation cache off: a
+    program compiled for a described chip is written to the cache but
+    cannot be read back without one."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -53,9 +62,14 @@ def one_chip():
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         try:
-            yield SingleDeviceSharding(topo.devices[0])
+            yield topo
         finally:
             jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _program(name, sharding):
@@ -104,45 +118,87 @@ _PASS_THROUGH = {"parameter", "tuple", "get-tuple-element", "while",
 _INSTR = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (.+?) ([a-z][\w\-]*)\(")
 
 
-def _whole_table_ops(hlo_text):
+def _whole_table_ops(hlo_text, rows):
     """``(opcode, line)`` of each instruction whose result has the table's
-    row count and which is not a pass-through or a gather."""
+    row count ``rows`` and which is not a pass-through or a gather."""
     out = []
     for line in hlo_text.splitlines():
         m = _INSTR.match(line)
-        if (m and re.search(rf"\b{SF10_ROWS}\b", m.group(1))
+        if (m and re.search(rf"\b{rows}\b", m.group(1))
                 and m.group(2) not in _PASS_THROUGH):
             out.append((m.group(2), line.strip()[:160]))
     return out
 
 
+def _sharded_tier_program(topo, c):
+    """The row-sharded pool's tier step for the four described chips at SF
+    300, with its arguments as shapes placed as the mesh pool places them.
+
+    The pool is built on one device (``mesh=False``, the same layout and
+    step parameters) around a stand-in table of SF 300's groups, which no
+    step reads; then given the described chips' mesh."""
+    mesh = Mesh(np.asarray(topo.devices), ("data",))
+    data = GroupedData(jnp.zeros((8, c), jnp.float32), SF300_OFFSETS)
+    with pytest.MonkeyPatch.context() as mp:
+        # Keep the stand-in small: no padding to SF 300's rows.
+        mp.setattr(core_mesh, "pad_rows", lambda v, rows: np.asarray(v))
+        pool = LanePool(data, B=300, use_kernel=True,
+                        data_shards=SF300_SHARDS, mesh=False)
+    pool._mesh = mesh
+    step, (cols, state, params, sspec), kw = pool._tier_program(
+        pool._tiers[0])
+    assert isinstance(cols, tuple) and len(cols) == c
+
+    def shape(x, spec=P()):
+        return jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    col = shape(jax.ShapeDtypeStruct((SF300_SHARDS * SF300_SHARD_ROWS,),
+                                     jnp.float32), P("data"))
+    state = jax.tree_util.tree_map(shape, state)._replace(
+        buf=shape(state.buf, P(None, None, "data", None)))
+    params = jax.tree_util.tree_map(shape, params)._replace(
+        slot_idx=shape(params.slot_idx, P("data", None, None)))
+    return step, ((col,) * c, state, params,
+                  jax.tree_util.tree_map(shape, sspec)), kw
+
+
 @pytest.mark.parametrize("c", [1, 2])
-@pytest.mark.parametrize("grouped", [False, True], ids=["tier", "block"])
-def test_pool_step_has_no_whole_table_op(one_chip, grouped, c):
+@pytest.mark.parametrize("kind", ["tier", "block", "sharded"])
+def test_pool_step_has_no_whole_table_op(topo, one_chip, kind, c):
     """The pool's step at SF 10 (59,986,052 rows, abstract, no data) at the
     pool's default lanes, tiers and ``n_cap``, B=300, compiled for a v5e
-    with the kernels in: only the row gathers touch the table."""
-    # A stand-in table with SF 10's groups: the pool's shapes follow the
-    # offsets, the step's table argument is replaced below.
-    data = GroupedData(jnp.zeros((8, c), jnp.float32), SF10_OFFSETS)
+    with the kernels in: only the row gathers touch the table.  ``sharded``:
+    the row-sharded tier step over four chips at SF 300, where only the
+    gathers touch a chip's 449,997,273-row block of a column."""
     with pytest.MonkeyPatch.context() as mp:
         for ops in (pb_ops, seg_ops):
             mp.setattr(ops, "interpret_default", lambda: False)
-        pool = LanePool(data, B=300, use_kernel=True)
-        if grouped:
-            pool.submit_group(Query(func="avg", epsilon=0.05,
-                                    group_by=True))
-            step, args, kw = pool._block_program(*pool._block_shapes)
+        if kind == "sharded":
+            rows = SF300_SHARD_ROWS
+            step, args, kw = _sharded_tier_program(topo, c)
+            txt = step.lower(*args, **kw).compile().as_text()
         else:
-            step, args, kw = pool._tier_program(pool._tiers[0])
-        assert isinstance(args[0], tuple) and len(args[0]) == c
-        cols = tuple(jax.ShapeDtypeStruct((SF10_ROWS,), jnp.float32,
-                                          sharding=one_chip)
-                     for _ in range(c))
-        rest = jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
-                                           sharding=one_chip), args[1:])
-        txt = step.lower(cols, *rest, **kw).compile().as_text()
+            rows = SF10_ROWS
+            # A stand-in table with SF 10's groups: the pool's shapes
+            # follow the offsets, the step's table argument is replaced
+            # below.
+            data = GroupedData(jnp.zeros((8, c), jnp.float32), SF10_OFFSETS)
+            pool = LanePool(data, B=300, use_kernel=True)
+            if kind == "block":
+                pool.submit_group(Query(func="avg", epsilon=0.05,
+                                        group_by=True))
+                step, args, kw = pool._block_program(*pool._block_shapes)
+            else:
+                step, args, kw = pool._tier_program(pool._tiers[0])
+            assert isinstance(args[0], tuple) and len(args[0]) == c
+            cols = tuple(jax.ShapeDtypeStruct((SF10_ROWS,), jnp.float32,
+                                              sharding=one_chip)
+                         for _ in range(c))
+            rest = jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                               sharding=one_chip), args[1:])
+            txt = step.lower(cols, *rest, **kw).compile().as_text()
     assert "tpu_custom_call" in txt
     assert re.search(rf"\bgather\(", txt)
-    assert _whole_table_ops(txt) == []
+    assert _whole_table_ops(txt, rows) == []
