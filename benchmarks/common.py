@@ -59,39 +59,15 @@ def simulated_confidence(
 
 
 class CsvEmitter:
-    """Collects `name,us_per_call,derived` rows (skeleton contract).
-
-    ``records`` keeps the same rows structured (name, us_per_call, and the
-    raw derived dict) so drivers can serialize machine-readable outputs
-    (benchmarks/run.py ``--json``) without re-parsing the CSV strings.
-    """
+    """Collects `name,us_per_call,derived` rows (skeleton contract)."""
 
     def __init__(self):
         self.rows = []
-        self.records = []
 
     def add(self, name: str, seconds: float, derived: Dict):
         derived_s = ";".join(f"{k}={v}" for k, v in derived.items())
         self.rows.append((name, seconds * 1e6, derived_s))
-        self.records.append(
-            {"bench": name, "us_per_call": seconds * 1e6, **derived})
         print(f"{name},{seconds * 1e6:.1f},{derived_s}", flush=True)
 
     def header(self):
         print("name,us_per_call,derived", flush=True)
-
-    def json_rows(self, prefix: str, keys=("bench", "us_per_call",
-                                           "rows_touched")):
-        """Machine-readable rows for one section (names under ``prefix``).
-
-        Rows are SPARSE: only keys a benchmark actually populated are
-        emitted -- sections share one artifact, and padding every row
-        with the union schema's nulls buries the real fields.  Consumers
-        must ``.get()`` tolerantly.
-        """
-        out = []
-        for rec in self.records:
-            if not rec["bench"].startswith(prefix):
-                continue
-            out.append({k: rec[k] for k in keys if k in rec})
-        return out

@@ -9,9 +9,9 @@ priority), is submitted into the live arrival queue, and is collected with
 a non-blocking submit/poll/pump loop -- answers stream back as lanes
 retire, tight-epsilon stragglers keep ticking while loose queries overtake
 them through freed lanes.  Host-only queries (predicates, Linf, ordering)
-ride the same session and route to the host engine.  The final batch goes
-through ``AQPService.answer`` -- the synchronous compatibility wrapper
-over the same session machinery.
+ride the same session and route to the host engine.  The final batch is
+submitted whole and collected with ``drain()``, the synchronous shape of
+the same session machinery.
 """
 import argparse
 import time
@@ -21,7 +21,7 @@ import numpy as np
 from repro.aqp import AQPEngine, Query, Request
 from repro.core.extensions import metric_value
 from repro.data.tpch import add_group_bias, make_lineitem
-from repro.serve import AQPService, AQPSession
+from repro.serve import AQPSession
 
 
 def main():
@@ -145,17 +145,19 @@ def main():
     print(f"pool counters: shed={pst['shed']} degraded={pst['degraded']} "
           f"migrations={pst['migrations']}")
 
-    # --- the synchronous compat wrapper over the same machinery ---
-    svc = AQPService(data, B=300, n_min=1000, n_max=2000, seed=1)
+    # --- a whole batch: submit everything, then drain ---
+    sess3 = AQPSession(data, B=300, n_min=1000, n_max=2000, seed=1)
     batch = [Query(func="avg", epsilon=0.02 * avg_mag),
              Query(func="var", epsilon=0.05 * avg_mag**2),
              Query(func="sum", epsilon=0.02 * sum_mag)]
     t0 = time.perf_counter()
-    rs = svc.answer(batch)
-    print(f"\nAQPService.answer (compat wrapper): {len(rs)} queries in "
+    for q in batch:
+        sess3.submit(Request(query=q))
+    rs = sess3.drain()
+    print(f"\nsubmit + drain: {len(rs)} queries in "
           f"{time.perf_counter() - t0:.2f}s, all "
           f"{'ok' if all(r.success for r in rs) else 'FAILED'}; "
-          f"pool={'yes' if svc._lane_pool is not None else 'no'}")
+          f"routes {sorted({r.route.value for r in rs})}")
 
 
 if __name__ == "__main__":

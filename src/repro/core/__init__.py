@@ -26,7 +26,6 @@ from .fused import (
     LaneParams,
     LaneState,
     fused_l2miss,
-    fused_l2miss_batch,
     fused_l2miss_lanes,
     fused_step,
     init_lane_state,
@@ -41,7 +40,7 @@ __all__ = [
     "MissConfig", "MissFailure",
     "MissTrace", "baselines", "bootstrap", "error_model", "estimators",
     "evaluate", "exact_answer", "extensions", "fused_l2miss",
-    "fused_l2miss_batch", "fused_l2miss_lanes", "fused_step",
+    "fused_l2miss_lanes", "fused_step",
     "init_lane_state", "lanes_result", "make_lane_params",
     "metric_value", "order_bound", "run_diffmiss",
     "run_l2miss", "run_lpmiss", "run_maxmiss", "run_miss",

@@ -32,7 +32,7 @@ buffers:
 
 ``sample_key`` (optional, defaults to ``key``) seeds the slot->row binding
 separately from the bootstrap stream, so a server can share one permuted
-prefix across many queries (serve/aqp_service.py) while keeping bootstrap
+prefix across many queries (serve/session.py) while keeping bootstrap
 replicates independent.
 
 Resumable step architecture (phase D): the loop state is the explicit
@@ -54,8 +54,12 @@ by moment-family index (``LaneParams.est_fids``) routed through
 mean/sum/count/std/var/proportion queries share one resident program
 instead of one dispatch per func group.
 
-``fused_l2miss_batch`` keeps the legacy vmap-over-tables entry for batches
-of *different* same-shape datasets.
+Entry points: the lane pool (serve/lane_pool.py) ticks :func:`fused_step`
+and the sharded step; :func:`fused_l2miss_lanes` and :func:`fused_l2miss`
+run the same bodies as closed loops, the solo references the tests hold
+the pool to; :func:`fused_grouped` is the closed loop of one GROUP BY
+block, which the host engine runs for grouped requests the pool cannot
+take.
 """
 from __future__ import annotations
 
@@ -208,8 +212,8 @@ def seg_ladder(seg_cap: int, n_max: int) -> Tuple[int, ...]:
     The phase-I analogue of :func:`bucket_ladder`: a grouped block's tick
     scans ONE packed stream of all active lanes' windows, padded up to the
     smallest rung covering the union watermark.  Exposed so the pool's cost
-    model and the benchmark's rows-scanned accounting price exactly the
-    rung the compiled step executes.
+    model and the tests' rows-scanned accounting price exactly the rung
+    the compiled step executes.
     """
     return _window_ladder(seg_cap, min(sampling.bucket_cap(n_max), seg_cap))
 
@@ -1187,7 +1191,7 @@ def make_sharded_step(mesh, *, num_ticks: int = 1, **statics):
     LaneState leaf except ``buf`` stays replicated.
 
     Memoized on ``(mesh, num_ticks, statics)``: callers that rebuild pools
-    (benchmarks, serving rebuilds) share ONE jitted program instead of
+    (serving rebuilds, the tests) share ONE jitted program instead of
     recompiling per instance -- a mesh step compile is seconds, a pool
     lifetime often is not.  The memo is a small LRU (a long-lived server
     cycling many pool configurations must not pin every program it ever
@@ -1761,45 +1765,3 @@ def fused_grouped(
         iterations=res.iterations, success=res.success, failed=res.failed,
         beta=res.beta, r2=res.r2, profile_n=res.profile_n[:, :, 0],
         profile_e=res.profile_e, rows_sampled=res.rows_sampled)
-
-
-def fused_l2miss_batch(values_batch, offsets, scale_batch, keys, epsilons,
-                       delta, sample_keys=None, **static_kwargs):
-    """Batch entry point: shared-operand lanes or legacy per-lane tables.
-
-    * ``values_batch (N, c)`` -- SHARED-OPERAND lanes (SS7 phase C): the one
-      resident table is never copied per lane; only
-      ``scale_batch (q, m)``, ``keys (q, 2)``, ``epsilons (q,)``, ``delta``
-      (scalar or ``(q,)``) and ``sample_keys`` carry the lane axis.  Runs
-      :func:`fused_l2miss_lanes` -- one while_loop, scalar width-bucket
-      switch, exactly one XLA dispatch.  ``sample_keys=None`` derives
-      per-lane bindings from ``keys``; a single ``(2,)`` key shares ONE
-      permuted prefix across the batch (the server epoch policy); ``(q, 2)``
-      pins one per lane.
-    * ``values_batch (q, N, c)`` -- legacy vmap over per-lane tables (same
-      shapes, different data).  vmap turns the data-dependent width-bucket
-      switch into execute-all-branches, so this path forces
-      ``adaptive=False`` (full-width ESTIMATE, the phase-B behavior).
-
-    Offsets are shared (same grouping layout) in both configurations;
-    per-query convergence is handled inside the loop either way.
-    """
-    epsilons = jnp.asarray(epsilons, jnp.float32)
-    q = epsilons.shape[0]
-    deltas = jnp.broadcast_to(jnp.asarray(delta, jnp.float32), (q,))
-    if jnp.ndim(values_batch) == 2:
-        return fused_l2miss_lanes(
-            values_batch, offsets, scale_batch, keys, epsilons, deltas,
-            sample_keys, **static_kwargs)
-    static_kwargs["adaptive"] = False
-    fn = partial(fused_l2miss, **static_kwargs)
-    if sample_keys is not None and jnp.ndim(sample_keys) == 1:
-        # A single shared (2,) key: tile it across the vmapped lanes (the 2D
-        # shared-operand path above handles it natively).
-        sample_keys = jnp.broadcast_to(sample_keys, (q,) + sample_keys.shape)
-    if sample_keys is None:
-        return jax.vmap(lambda v, s, k, e, d: fn(v, offsets, s, k, e, d))(
-            values_batch, scale_batch, keys, epsilons, deltas)
-    return jax.vmap(
-        lambda v, s, k, e, d, sk: fn(v, offsets, s, k, e, d, sample_key=sk))(
-        values_batch, scale_batch, keys, epsilons, deltas, sample_keys)

@@ -109,7 +109,7 @@ class _L2MissSubroutines:
         self.key = sampling.root_key(cfg.seed)
         # Incremental permuted-prefix sampler: nested across iterations, so
         # growing n touches only the extension (DESIGN.md SS3.2).  A caller
-        # may pass a resident store (AQPEngine/AQPService) to reuse prefixes
+        # may pass a resident store (AQPEngine/AQPSession) to reuse prefixes
         # across queries too.
         self.store = store if store is not None else sampling.SampleStore(
             data, seed=cfg.seed)

@@ -9,8 +9,7 @@ row index past the last group offset) marking padding.
 On CPU containers a multi-device mesh is simulated with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` -- which must be in
 the environment BEFORE jax is imported (:func:`host_device_flag` builds
-the flag string; benchmarks/run.py ``--devices`` and the CI multi-device
-job both use it).
+the flag string; the CI multi-device job sets it).
 """
 from __future__ import annotations
 

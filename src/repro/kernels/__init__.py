@@ -24,7 +24,7 @@ def resolve_use_kernel(mode: "bool | str") -> bool:
     ``True``/``False`` are taken literally (``True`` on CPU runs the kernels
     in interpret mode -- the parity-test configuration).  ``"auto"`` selects
     the Pallas path on TPU and the jnp path everywhere else, so production
-    entry points (AQPEngine/AQPService) can default to the fast path without
+    entry points (AQPEngine/AQPSession) can default to the fast path without
     dragging interpret-mode kernels into CPU serving.
     """
     if isinstance(mode, str):

@@ -1,5 +1,4 @@
 from ..aqp.query import Request
-from .aqp_service import AQPResponse, AQPService
 from .batching import ContinuousBatcher
 from .lane_pool import GroupPoolResponse, LanePool, PoolResponse
 from .planner import Planner, PoolPlan, Route
@@ -13,9 +12,9 @@ from .warm_cache import CachedAnswer, WarmCache, WarmEntry
 # request lives at ``repro.serve.batching.Request``; import it from the
 # submodule.
 __all__ = [
-    "AQPResponse", "AQPService", "AQPSession", "AdmissionController",
-    "CachedAnswer", "ContinuousBatcher", "CostModel", "DegradePlan",
-    "FairQueue", "GroupPoolResponse", "LanePool", "Planner", "PoolPlan",
-    "PoolResponse", "Request", "Route", "SessionResponse", "SessionTicket",
-    "WarmCache", "WarmEntry", "eps_for_budget",
+    "AQPSession", "AdmissionController", "CachedAnswer",
+    "ContinuousBatcher", "CostModel", "DegradePlan", "FairQueue",
+    "GroupPoolResponse", "LanePool", "Planner", "PoolPlan", "PoolResponse",
+    "Request", "Route", "SessionResponse", "SessionTicket", "WarmCache",
+    "WarmEntry", "eps_for_budget",
 ]

@@ -1,7 +1,7 @@
 """Continuous lane-pool AQP serving (DESIGN.md SS7 phases D + E).
 
-The batched phase-C path answers a func group as one closed ``while_loop``:
-converged lanes stay frozen-but-resident until the slowest lane finishes, so
+A closed ``while_loop`` over a batch of lanes (``fused_l2miss_lanes``) keeps
+converged lanes frozen-but-resident until the slowest lane finishes, so
 under mixed-epsilon traffic most of the program's lane-ticks are spent on
 already-answered queries.  This module ports the seed repo's continuous-
 batching pattern (serve/batching.py: lockstep decode slots with splice-in

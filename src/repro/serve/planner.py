@@ -1,26 +1,21 @@
 """Routing planner for the asynchronous serving session (DESIGN.md SS7
 phase F).
 
-One explicit component owns the decision the old ``batch_fused in {True,
-False, 'pool', 'auto'}`` tri-state hid inside ``AQPService.answer``: where
-does a request run?  :meth:`Planner.route` inspects the query itself (func,
-metric, bound form, predicate), the current pool occupancy, and how many
-fusable requests are waiting in the same admission wave, and returns an
-explicit :class:`Route`:
+:meth:`Planner.route` inspects the query itself (func, metric, bound form,
+predicate, GROUP BY) and returns an explicit :class:`Route`:
 
-* ``POOL``    -- the continuous heterogeneous lane pool (phase D/E): real
-  per-query latency, mid-flight admission, retire-and-refill.
-* ``BATCHED`` -- phase-C closed-loop batching: one dispatch per func group,
-  amortized latency (kept for benchmarks and forced-mode compat).
-* ``LOOP``    -- one fused dispatch per query (the benchmark baseline, and
-  the cheapest plan for a singleton with an idle pool: no pool build).
-* ``HOST``    -- the host engine (order/diff/linf/lp metrics, relative
-  bounds, predicates, quantiles -- everything the fused program can't run).
+* ``POOL`` -- the continuous heterogeneous lane pool (phase D/E): real
+  per-query latency, mid-flight admission, retire-and-refill.  Every
+  fusable solo request rides it, and so does every grouped request a pool
+  block can serve (phase I).
+* ``WARM`` -- a warm-cache hit (phase H): a warm-started pool lane or
+  block, or an exact replay answered with zero dispatches.
+* ``HOST`` -- the host engine (order/diff/linf/lp metrics, relative
+  bounds, predicates, quantiles, and grouped requests of a sharded
+  session -- everything the pool can't run).
 
-The planner also owns **continuous re-tuning** of the pool configuration.
-The phase-E heuristics (`AQPService._auto_pool_config`) were frozen from
-the FIRST pooled batch; here they become a sliding-window policy over the
-live request stream:
+The planner also owns **continuous re-tuning** of the pool configuration,
+a sliding-window policy over the live request stream:
 
 * ``ticks_per_sync`` follows the epsilon spread of the last ``window``
   fusable requests (wide spread = straggler-prone -> sync every tick so
@@ -57,8 +52,6 @@ FUSABLE = ("avg", "proportion", "var", "std", "sum", "count")
 class Route(enum.Enum):
     """Where a request runs (the planner's explicit routing decision)."""
     POOL = "pool"
-    BATCHED = "batched"
-    LOOP = "loop"
     HOST = "host"
     # Phase H: a warm-cache hit.  Coefficient hits ride the pool with a
     # warm-started lane (admitted into the narrowest tier -- their windows
@@ -98,31 +91,15 @@ class PoolPlan:
 
 
 class Planner:
-    """Route requests and continuously re-tune the pool configuration.
-
-    ``mode`` forces a route for fusable requests (the compat surface of the
-    old ``batch_fused``): ``Route.POOL`` / ``Route.BATCHED`` / ``Route.LOOP``
-    force that path, ``None`` (auto) picks the pool whenever it is already
-    busy or >= 2 fusable requests arrived in the same wave, and the
-    singleton loop otherwise.  Non-fusable requests always route HOST.
-    """
+    """Route requests and continuously re-tune the pool configuration."""
 
     MAX_LANES = 8
     SPREAD_THRESHOLD = 1.5
 
-    def __init__(self, *, mode: Optional[Route] = None, window: int = 32,
-                 cooldown: int = 32, pool_lanes: Optional[int] = None,
+    def __init__(self, *, window: int = 32, cooldown: int = 32,
+                 pool_lanes: Optional[int] = None,
                  pool_ticks_per_sync: Optional[int] = None,
-                 data_shards: int = 1, slo_native: bool = False):
-        if mode is not None and not isinstance(mode, Route):
-            raise TypeError(f"mode must be a Route or None; got {mode!r}")
-        self.mode = mode
-        # Phase J: with a degrade-armed pool behind the session, a fusable
-        # request that CARRIES a deadline should always ride the pool --
-        # only the pool can relax its epsilon or shed it with a pilot
-        # answer; the singleton LOOP would just run it to completion and
-        # miss.  Auto mode only (forced modes stay forced).
-        self.slo_native = bool(slo_native)
+                 data_shards: int = 1):
         self.window = int(window)
         self.cooldown = int(cooldown)
         # Mesh-aware tier sizing (phase G): a sharded pool's per-tick
@@ -140,44 +117,24 @@ class Planner:
         self.retunes = 0          # ticks_per_sync changes applied
 
     # -- routing ------------------------------------------------------------
-    def route(self, request: Request, *, pending_fusable: int,
-              pool_busy: bool, warm: bool = False) -> Route:
+    def route(self, request: Request, *, warm: bool = False) -> Route:
         """Pick the route for one request.
 
-        ``pending_fusable`` is the number of fusable requests in the same
-        admission wave (this request included); ``pool_busy`` whether the
-        live pool currently holds in-flight or queued work.  ``warm``
-        marks a warm-cache coefficient hit: it takes the WARM fast path
-        (a warm-started pool lane) unless the operator forced a
-        non-pool mode -- forced BATCHED/LOOP stay forced (compat).
+        ``warm`` marks a warm-cache coefficient hit: it takes the WARM
+        path (a warm-started pool lane or block).
 
-        GROUP BY requests have exactly two homes: the pool's shared-scan
-        lane block (phase I: one gather + one segment ESTIMATE per tick,
-        whatever the group count) when the clause qualifies and the layout
-        is single-device, else the host engine.  Forced BATCHED/LOOP modes
-        do not apply -- those are solo-lane shapes.
+        * grouped: the pool's shared-scan lane block (phase I: one gather
+          + one segment ESTIMATE per tick, whatever the group count) when
+          the clause qualifies and the layout is single-device, else HOST;
+        * solo, not fusable: HOST;
+        * solo, fusable: WARM on a warm hit, else POOL.
         """
         if request.query.group_by:
             if not grouped_fusable(request) or self.data_shards > 1:
                 return Route.HOST
-            return Route.WARM if warm else Route.POOL
-        if not fusable(request):
+        elif not fusable(request):
             return Route.HOST
-        if warm and self.mode in (None, Route.POOL, Route.WARM):
-            return Route.WARM
-        if self.mode is not None:
-            return self.mode
-        # Auto: join a busy pool (mid-flight admission is the point of the
-        # session API); build/use the pool for multi-request waves; serve
-        # the cold singleton with one dispatch -- no pool to build, and a
-        # solo closed loop beats pool ticking overhead.  Under slo_native
-        # a deadline-carrying request routes POOL unconditionally: the
-        # pool is where degradation and shedding live.
-        if self.slo_native and request.deadline_s is not None:
-            return Route.POOL
-        if pool_busy or pending_fusable >= 2:
-            return Route.POOL
-        return Route.LOOP
+        return Route.WARM if warm else Route.POOL
 
     # -- observation --------------------------------------------------------
     def observe_request(self, request: Request) -> None:

@@ -1,9 +1,8 @@
 """Asynchronous SLO-aware serving session (DESIGN.md SS7 phase F).
 
-The batch-synchronous ``AQPService.answer(List[Query])`` drains the lane
-pool completely between calls: a query arriving mid-flight waits for the
-whole previous batch.  :class:`AQPSession` replaces that contract with an
-open-loop one -- the shape a service under continuous traffic needs:
+:class:`AQPSession` serves an open-loop stream -- the shape a service
+under continuous traffic needs, where a query arriving mid-flight must not
+wait for the whole previous batch:
 
 * :meth:`submit` (``Request -> SessionTicket``) enqueues a request into the
   live arrival queue and returns immediately; the request carries its SLO
@@ -15,28 +14,25 @@ open-loop one -- the shape a service under continuous traffic needs:
   splices into a freed lane without waiting for the pool to drain.
 * :meth:`poll` (non-blocking) pops a finished response, or returns None
   while the request is still queued / in flight.
-* :meth:`drain` pumps until idle -- the compatibility shape:
-  ``AQPService.answer`` is now a thin submit-all-then-drain wrapper.
+* :meth:`drain` pumps until idle: submit a batch, then drain it.
 
 Routing is the planner's explicit :class:`Route` enum -- POOL (continuous
-lanes, real submit->harvest latency), BATCHED (phase-C closed-loop func
-groups, amortized dispatch/k latency), LOOP (one dispatch per query),
-HOST (everything the fused program can't run).  The planner also re-tunes
+lanes and grouped blocks, real submit->harvest latency), WARM (warm-cache
+hits), HOST (everything the pool can't run).  The planner also re-tunes
 the pool continuously from a sliding window of the live stream: sync
 cadence (``ticks_per_sync``) may change between any two dispatches, and
 lane-count rebuilds are requested by the planner and honored here at idle
 points only (no resident state to migrate).
 
-Sample reuse (SS3.2) carries over from the service: one resident
-SampleStore per dataset shared by the host engine and every request, one
-``sample_key`` per epoch pinning the fused slot->row binding.  The epoch
-policy is now completion-counted, and a reshuffle firing while pool
-tickets are in flight DEFERS the pool's rebind to an idle point
-(:meth:`LanePool.request_sample_key`) -- resident prefixes are defined by
-the old binding, so rotating under them would break the nesting invariant.
+Sample reuse (SS3.2): one resident SampleStore per dataset shared by the
+host engine and every request, one ``sample_key`` per epoch pinning the
+fused slot->row binding.  The epoch policy is completion-counted, and a
+reshuffle firing while pool tickets are in flight DEFERS the pool's rebind
+to an idle point (:meth:`LanePool.request_sample_key`) -- resident
+prefixes are defined by the old binding, so rotating under them would
+break the nesting invariant.
 
-Accounting matches the service it replaces (``fused_dispatches``,
-``rows_touched``), with one deliberate fix: fused rows are counted at
+Accounting (``fused_dispatches``, ``rows_touched``) counts fused rows at
 HARVEST time, so a response nobody ever collects (a residue ticket of an
 abandoned caller) still lands in ``rows_touched``.
 """
@@ -45,7 +41,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
-from typing import Deque, Dict, List, Optional, Tuple, Union
+from typing import Deque, Dict, List, Optional, Union
 
 import jax
 import jax.numpy as jnp
@@ -53,8 +49,6 @@ import numpy as np
 
 from ..aqp.engine import AQPEngine
 from ..aqp.query import Query, Request
-from ..core import estimators
-from ..core.fused import fused_l2miss_batch
 from ..core.sampling import GroupedData, SampleStore
 from ..kernels import resolve_use_kernel
 from .lane_pool import GroupPoolResponse, LanePool
@@ -77,10 +71,9 @@ class SessionResponse:
     """One finished request.
 
     ``latency_s`` is the real submit -> completion time on every route --
-    the clock the SLO is judged against.  ``wall_time_s`` keeps the
-    route-specific compute-latency semantics of the synchronous service
-    (real latency on POOL/LOOP/HOST; amortized dispatch/k on BATCHED), so
-    the ``answer()`` compat wrapper reports exactly what it used to.
+    the clock the SLO is judged against.  ``wall_time_s`` is the
+    request's own compute time: submit -> harvest on a pool lane, the
+    synchronous call on HOST, 0 for an exact cache replay.
     """
     rid: int
     theta: np.ndarray
@@ -166,22 +159,18 @@ class AQPSession:
         self.mesh = mesh
         # Phase J: overload-native scheduling, all OPT-IN (the phase-E/F
         # session is the exact special case).  ``degrade`` arms
-        # deadline-driven epsilon relaxation + load shedding in the pool
-        # (and biases the auto planner toward POOL for deadline-carrying
-        # requests -- only the pool can degrade); ``wfq`` arms per-tenant
-        # weighted fair queueing; ``migrate`` arms cross-tier lane
-        # migration.
+        # deadline-driven epsilon relaxation + load shedding in the pool;
+        # ``wfq`` arms per-tenant weighted fair queueing; ``migrate`` arms
+        # cross-tier lane migration.
         self.degrade = bool(degrade)
         self.wfq = bool(wfq)
         self.tenant_weights = tenant_weights
         self.migrate = bool(migrate)
         self.max_degrade = float(max_degrade)
         self.planner = (planner if planner is not None
-                        else Planner(data_shards=self.data_shards,
-                                     slo_native=self.degrade))
+                        else Planner(data_shards=self.data_shards))
         self.pool_tiers = pool_tiers
         self.key = jax.random.PRNGKey(seed)
-        self._offsets = jnp.asarray(data.offsets)
         self._m = data.num_groups
         # Reuse/decorrelation policy: one sample epoch serves up to
         # ``reshuffle_every`` COMPLETED requests, then prefixes are redrawn
@@ -200,8 +189,8 @@ class AQPSession:
         # Phase H: learned warm-start + answer cache.  OPT-IN: repeat
         # detection changes how a bit-identical resubmission is served
         # (replayed, zero dispatches), so callers that rely on every
-        # submission running -- parity tests, scheduling benchmarks --
-        # keep the default off.
+        # submission running -- parity tests, the benchmark -- keep the
+        # default off.
         if isinstance(warm_cache, WarmCache):
             self.cache: Optional[WarmCache] = warm_cache
         else:
@@ -376,7 +365,6 @@ class AQPSession:
         if data is not None:
             self.data = data
             self.engine.data = data
-            self._offsets = jnp.asarray(data.offsets)
             self._m = data.num_groups
         self.store.refresh(self.data)
         self._pool = None               # resident prefixes follow the data
@@ -506,16 +494,13 @@ class AQPSession:
 
     # -- admission ----------------------------------------------------------
     def _admit(self) -> None:
-        """Route every queued arrival; synchronous routes (BATCHED / LOOP /
-        HOST) complete inside this call, POOL submissions ride subsequent
-        pumps."""
+        """Route every queued arrival; HOST requests complete inside this
+        call, POOL and WARM submissions ride subsequent pumps."""
         if not self._arrivals:
             return
         wave = [self._inflight[rid] for rid in self._arrivals]
         self._arrivals.clear()
         pool = self._pool
-        pool_busy = pool is not None and bool(
-            pool.busy_lanes or pool.busy_blocks or pool.queue_depth)
         # Warm-cache hits are short-lived lanes by construction; feeding
         # them into the planner's sliding windows would let a burst of
         # repeats inflate the lane-count drift signal and trigger rebuilds.
@@ -528,9 +513,8 @@ class AQPSession:
             n_fus + ((pool.busy_lanes + pool.queue_depth) if pool else 0))
         groups: Dict[Route, List[_InFlight]] = {}
         for e in wave:
-            e.route = self.planner.route(
-                e.request, pending_fusable=n_fus, pool_busy=pool_busy,
-                warm=e.warm_n0 is not None)
+            e.route = self.planner.route(e.request,
+                                         warm=e.warm_n0 is not None)
             groups.setdefault(e.route, []).append(e)
         try:
             # WARM rides the pool machinery (a warm-started lane admitted
@@ -539,17 +523,13 @@ class AQPSession:
                 groups.get(Route.WARM, [])
             if pooled_entries:
                 self._admit_pool(pooled_entries)
-            if groups.keys() & {Route.BATCHED, Route.LOOP, Route.HOST}:
-                # The synchronous routes stall every lane until they return.
+            if Route.HOST in groups:
+                # The synchronous route stalls every lane until it returns.
                 with self.recorder.phase("inline_route"):
-                    if Route.BATCHED in groups:
-                        self._run_batched(groups[Route.BATCHED])
-                    if Route.LOOP in groups:
-                        self._run_loop(groups[Route.LOOP])
-                    for e in groups.get(Route.HOST, ()):
+                    for e in groups[Route.HOST]:
                         self._run_host(e)
         except BaseException:
-            # A synchronous route died mid-wave (engine error, interrupt).
+            # The synchronous route died mid-wave (engine error, interrupt).
             # Entries not yet completed and not handed to the pool would
             # otherwise be stranded in _inflight with no way back to the
             # scheduler -- re-queue them so the next pump() retries (the
@@ -651,85 +631,7 @@ class AQPSession:
                 delivered_B=getattr(r, "delivered_B", None),
                 degraded=degraded, shed=shed)
 
-    # -- synchronous routes -------------------------------------------------
-    def _group_scale(self, func: str, k: int):
-        """(k, m) per-lane scale rows for one func (SS2.2.1 transform)."""
-        row = jnp.asarray(
-            estimators.population_scale_row(func, self.data.scale))
-        return jnp.broadcast_to(row, (k, self._m))
-
-    def _dispatch_fused(self, func: str, queries: List[Query], keys):
-        """One batched fused program for ``len(queries)`` same-func lanes."""
-        k = len(queries)
-        eps = jnp.asarray([q.epsilon for q in queries], jnp.float32)
-        deltas = jnp.asarray([q.delta for q in queries], jnp.float32)
-        res = fused_l2miss_batch(
-            self.data.values, self._offsets,
-            self._group_scale(func, k), jnp.stack(keys), eps,
-            deltas, sample_keys=self._sample_key,
-            est_name=func, B=self.B, n_min=self.n_min, n_max=self.n_max,
-            l=min(self._m + 2, 12), max_iters=self.max_iters,
-            n_cap=self.n_cap, use_kernel=self.use_kernel)
-        self.fused_dispatches += 1
-        return res
-
-    def _by_func(self, entries: List[_InFlight]
-                 ) -> List[Tuple[str, List[_InFlight]]]:
-        by_func: Dict[str, List[_InFlight]] = {}
-        for e in entries:
-            by_func.setdefault(e.request.query.func, []).append(e)
-        return list(by_func.items())
-
-    def _run_batched(self, entries: List[_InFlight]) -> None:
-        """Phase-C closed-loop batching: ONE dispatch per func group;
-        amortized per-query wall time (dispatch / lane count -- per-lane
-        wall clock inside one program is not observable)."""
-        for func, group in self._by_func(entries):
-            keys = self._lane_keys(group)
-            t0 = time.perf_counter()
-            res = self._dispatch_fused(
-                func, [e.request.query for e in group], keys)
-            theta, errs, succ, ns, rows, betas, fails, its = \
-                self.recorder.device_get(
-                    (res.theta, res.error, res.success, res.n,
-                     res.rows_sampled, res.beta, res.failed, res.iterations))
-            per_q = (time.perf_counter() - t0) / len(group)
-            for lane, e in enumerate(group):
-                self._fused_rows += int(rows[lane])
-                self._cache_insert(
-                    e, beta=betas[lane], n=ns[lane], theta=theta[lane],
-                    error=float(errs[lane]), success=bool(succ[lane]),
-                    failed=bool(fails[lane]), iterations=int(its[lane]))
-                self._complete(
-                    e, theta=theta[lane], error=float(errs[lane]),
-                    success=bool(succ[lane]), n=ns[lane],
-                    wall_time_s=per_q, queue_wait_s=0.0,
-                    route=Route.BATCHED, rows_sampled=int(rows[lane]))
-
-    def _run_loop(self, entries: List[_InFlight]) -> None:
-        """Per-query dispatch loop: k dispatches, timed individually."""
-        for func, group in self._by_func(entries):
-            keys = self._lane_keys(group)
-            for e, key in zip(group, keys):
-                t0 = time.perf_counter()
-                res = self._dispatch_fused(func, [e.request.query], [key])
-                theta, err, succ, n, rows, beta, failed, its = \
-                    self.recorder.device_get(
-                        (res.theta, res.error, res.success, res.n,
-                         res.rows_sampled, res.beta, res.failed,
-                         res.iterations))
-                rows = int(rows[0])
-                self._fused_rows += rows
-                self._cache_insert(
-                    e, beta=beta[0], n=n[0], theta=theta[0],
-                    error=float(err[0]), success=bool(succ[0]),
-                    failed=bool(failed[0]), iterations=int(its[0]))
-                self._complete(
-                    e, theta=theta[0], error=float(err[0]),
-                    success=bool(succ[0]), n=n[0],
-                    wall_time_s=time.perf_counter() - t0, queue_wait_s=0.0,
-                    route=Route.LOOP, rows_sampled=rows)
-
+    # -- the synchronous route ----------------------------------------------
     def _run_host(self, entry: _InFlight) -> None:
         """Host-engine fallback (order/diff/lp/linf/predicates/relative
         bounds/quantiles; grouped queries a pool block cannot serve --

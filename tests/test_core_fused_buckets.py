@@ -1,6 +1,6 @@
 """Width-bucketed fused ESTIMATE (DESIGN.md SS7 phase C): bucket invariance,
 kernel-vs-jnp parity, linf/l1 fused-vs-host parity, and shared-operand
-batched lanes vs solo runs."""
+lanes vs solo runs."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,7 +10,7 @@ from numpy.testing import assert_allclose
 from repro.core import estimators
 from repro.core.extensions import run_lpmiss, run_maxmiss
 from repro.core.fused import (FusedResult, _bucket_widths, fused_l2miss,
-                              fused_l2miss_batch)
+                              fused_l2miss_lanes)
 from repro.core.l2miss import MissConfig, exact_answer
 from repro.data import make_grouped
 
@@ -158,9 +158,9 @@ def test_shared_operand_batch_matches_solo(data):
     keys = jax.random.split(jax.random.PRNGKey(1), q)
     eps = jnp.asarray([0.15, 0.08, 0.2], jnp.float32)
     skey = jax.random.PRNGKey(7)
-    rb = fused_l2miss_batch(
+    rb = fused_l2miss_lanes(
         data.values, jnp.asarray(data.offsets), jnp.ones((q, 2), jnp.float32),
-        keys, eps, 0.05, sample_keys=skey, **KW)
+        keys, eps, jnp.full((q,), 0.05, jnp.float32), sample_keys=skey, **KW)
     assert isinstance(rb, FusedResult)
     assert bool(np.all(np.asarray(rb.success)))
     totals = np.asarray(rb.n).sum(axis=1)
@@ -180,7 +180,7 @@ def test_batch_per_lane_deltas(data):
     q = 2
     keys = jax.random.split(jax.random.PRNGKey(2), q)
     eps = jnp.asarray([0.15, 0.15], jnp.float32)
-    res = fused_l2miss_batch(
+    res = fused_l2miss_lanes(
         data.values, jnp.asarray(data.offsets), jnp.ones((q, 2), jnp.float32),
         keys, eps, jnp.asarray([0.05, 0.2], jnp.float32),
         sample_keys=jax.random.PRNGKey(9), **KW)
@@ -188,19 +188,19 @@ def test_batch_per_lane_deltas(data):
 
 
 def test_legacy_batch_shared_sample_key(data):
-    """The 3D (per-lane tables) path must accept the documented single (2,)
-    sample key by tiling it across lanes, matching the manual broadcast."""
+    """One shared (2,) sample key binds every lane to the same slot table:
+    the answers equal those with that key tiled per lane, (q, 2)."""
     q = 2
-    vals3 = jnp.broadcast_to(data.values, (q,) + data.values.shape)
     keys = jax.random.split(jax.random.PRNGKey(4), q)
     eps = jnp.asarray([0.15, 0.2], jnp.float32)
+    deltas = jnp.full((q,), 0.05, jnp.float32)
     skey = jax.random.PRNGKey(7)
-    r_shared = fused_l2miss_batch(
-        vals3, jnp.asarray(data.offsets), jnp.ones((q, 2), jnp.float32),
-        keys, eps, 0.05, sample_keys=skey, **KW)
-    r_tiled = fused_l2miss_batch(
-        vals3, jnp.asarray(data.offsets), jnp.ones((q, 2), jnp.float32),
-        keys, eps, 0.05,
+    r_shared = fused_l2miss_lanes(
+        data.values, jnp.asarray(data.offsets), jnp.ones((q, 2), jnp.float32),
+        keys, eps, deltas, sample_keys=skey, **KW)
+    r_tiled = fused_l2miss_lanes(
+        data.values, jnp.asarray(data.offsets), jnp.ones((q, 2), jnp.float32),
+        keys, eps, deltas,
         sample_keys=jnp.broadcast_to(skey, (q,) + skey.shape), **KW)
     assert bool(np.all(np.asarray(r_shared.success)))
     assert np.array_equal(np.asarray(r_shared.n), np.asarray(r_tiled.n))

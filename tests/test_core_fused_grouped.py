@@ -62,6 +62,36 @@ def test_block_matches_solo_runs():
         assert_allclose(blk.error[g], solo.error, rtol=1e-3)
 
 
+def _rung(widths, v):
+    return int(next((w for w in widths if v <= w), widths[-1]))
+
+
+def test_block_scans_fewer_rows_than_solo_lanes():
+    """One grouped block scans fewer ESTIMATE rows than G solo lanes for
+    the same query.  Each side is priced at the rungs its compiled step
+    runs, over active iterations only: the block one ``seg_ladder`` rung
+    over the packed sum of active prefixes per tick, a solo lane one
+    ``bucket_ladder`` rung per iteration."""
+    values, offsets, sizes = _make()
+    G = len(sizes)
+    key = jax.random.PRNGKey(42)
+    blk = jax.tree.map(np.asarray, fused.fused_grouped(
+        values, jnp.asarray(offsets), np.ones(G), key, EPS, DELTA,
+        est_name="avg", **SPEC))
+    solos = [_solo(values, offsets, sizes, key, g) for g in range(G)]
+
+    seg_w = fused.seg_ladder(
+        fused.grouped_seg_cap(offsets, SPEC["n_cap"]), SPEC["n_max"])
+    rows_block = sum(
+        _rung(seg_w, int(sum(blk.profile_n[g, t] for g in range(G)
+                             if t < blk.iterations[g])))
+        for t in range(int(blk.iterations.max())))
+    buck_w = fused.bucket_ladder(SPEC["n_cap"], SPEC["n_max"])
+    rows_solo = sum(_rung(buck_w, int(s.profile_n[t, 0]))
+                    for s in solos for t in range(int(s.iterations)))
+    assert 0 < rows_block < rows_solo
+
+
 def test_block_kernel_path_matches_jnp_path():
     """use_kernel routes ESTIMATE through segment_bootstrap_moments (the
     Pallas kernel; interpret off-TPU).  Trajectories must agree with the

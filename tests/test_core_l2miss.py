@@ -179,27 +179,28 @@ def test_fused_deterministic_given_keys(normal_exp_data):
 
 
 def test_fused_batch_vmap(normal_exp_data):
-    from repro.core.fused import fused_l2miss_batch
+    """Lanes of one closed loop: a tighter epsilon draws more rows."""
+    from repro.core.fused import fused_l2miss_lanes
 
     data = normal_exp_data
     q = 3
-    vals = jnp.broadcast_to(data.values, (q,) + data.values.shape)
     scales = jnp.ones((q, 2), jnp.float32)
     keys = jax.random.split(jax.random.PRNGKey(1), q)
     eps = jnp.asarray([0.1, 0.05, 0.2], jnp.float32)
-    res = fused_l2miss_batch(
-        vals, jnp.asarray(data.offsets), scales, keys, eps, 0.05,
-        est_name="avg", B=100, n_min=400, n_max=800, l=6,
-        max_iters=16, n_cap=1 << 13)
+    deltas = jnp.full((q,), 0.05, jnp.float32)
+    spec = dict(est_name="avg", B=100, n_min=400, n_max=800, l=6,
+                max_iters=16, n_cap=1 << 13)
+    res = fused_l2miss_lanes(
+        data.values, jnp.asarray(data.offsets), scales, keys, eps, deltas,
+        **spec)
     assert bool(np.all(np.asarray(res.success)))
     # Tighter eps -> more samples.
     totals = np.asarray(res.n).sum(axis=1)
     assert totals[1] >= totals[0] >= totals[2]
-    # Shared-prefix variant: one sample key tiled across the batch.
-    skey = jax.random.PRNGKey(7)
-    res2 = fused_l2miss_batch(
-        vals, jnp.asarray(data.offsets), scales, keys, eps, 0.05,
-        jnp.broadcast_to(skey, (q,) + skey.shape),
-        est_name="avg", B=100, n_min=400, n_max=800, l=6,
-        max_iters=16, n_cap=1 << 13)
+    # Shared-prefix variant: one sample key for every lane.
+    res2 = fused_l2miss_lanes(
+        data.values, jnp.asarray(data.offsets), scales, keys, eps, deltas,
+        jax.random.PRNGKey(7), **spec)
     assert bool(np.all(np.asarray(res2.success)))
+    totals2 = np.asarray(res2.n).sum(axis=1)
+    assert totals2[1] >= totals2[0] >= totals2[2]

@@ -184,98 +184,71 @@ def test_store_capacity_bucketing(small_data):
 
 
 # ---------------------------------------------------------------------------
-# Service-level reuse: one resident store, shared fused prefixes, reshuffle
+# Session-level reuse: one resident store, shared fused prefixes, reshuffle
 # ---------------------------------------------------------------------------
+
+def _answer(sess, queries):
+    """Submit ``queries`` to the session, drain it, return responses in
+    query order."""
+    from repro.aqp.query import Request
+
+    reqs = [Request(query=q) for q in queries]
+    for r in reqs:
+        sess.submit(r)
+    by_rid = {r.rid: r for r in sess.drain()}
+    return [by_rid[r.rid] for r in reqs]
+
 
 def test_aqp_service_resident_store_and_reshuffle():
     from repro.aqp.query import Query
     from repro.data import make_grouped
-    from repro.serve.aqp_service import AQPService
+    from repro.serve import AQPSession
 
     data = make_grouped(["normal", "exp"], 60_000, seed=11, biases=[4.0, 2.0])
-    svc = AQPService(data, B=100, n_min=300, n_max=600, max_iters=12,
-                     n_cap=1 << 12, seed=0, reshuffle_every=3)
-    assert svc.store is svc.engine.store      # one store, shared with engine
+    sess = AQPSession(data, B=100, n_min=300, n_max=600, max_iters=12,
+                      n_cap=1 << 12, seed=0, reshuffle_every=3)
+    assert sess.store is sess.engine.store    # one store, shared with engine
 
     qs = [Query(func="avg", epsilon=0.2), Query(func="avg", epsilon=0.15)]
-    rs = svc.answer(qs)
+    rs = _answer(sess, qs)
     assert all(r.success for r in rs)
-    epoch0 = svc.store.epoch
-    skey0 = np.asarray(svc._sample_key).copy()
+    epoch0 = sess.store.epoch
+    skey0 = np.asarray(sess._sample_key).copy()
 
     # Host-engine queries extend the same resident prefixes.
-    before = svc.rows_touched
-    r = svc.answer([Query(func="median", epsilon=0.3)])[0]
+    before = sess.rows_touched
+    r = _answer(sess, [Query(func="median", epsilon=0.3)])[0]
     assert r.success
-    assert svc.rows_touched > before
+    assert sess.rows_touched > before
 
     # The decorrelation policy rotated after >= 3 queries.
-    assert svc.store.epoch > epoch0
-    assert not np.array_equal(np.asarray(svc._sample_key), skey0)
+    assert sess.store.epoch > epoch0
+    assert not np.array_equal(np.asarray(sess._sample_key), skey0)
 
     # refresh() invalidates on data update and keeps serving.
-    svc.refresh(data)
-    r = svc.answer([Query(func="avg", epsilon=0.2)])[0]
+    sess.refresh(data)
+    r = _answer(sess, [Query(func="avg", epsilon=0.2)])[0]
     assert r.success
-
-
-def test_aqp_service_batched_single_dispatch():
-    """SS7 phase C serving contract: one fused dispatch per func group, with
-    per-lane answers identical to the per-query dispatch loop and honest
-    (amortized, non-cumulative) per-query wall times."""
-    import numpy as np
-
-    from repro.aqp.query import Query
-    from repro.data import make_grouped
-    from repro.serve.aqp_service import AQPService
-
-    data = make_grouped(["normal", "exp"], 60_000, seed=11, biases=[4.0, 2.0])
-    kw = dict(B=100, n_min=300, n_max=600, max_iters=12, n_cap=1 << 12,
-              seed=0, reshuffle_every=1000)
-    qs = ([Query(func="avg", epsilon=e, delta=d)
-           for e, d in [(0.2, 0.05), (0.15, 0.05), (0.25, 0.1), (0.3, 0.05)]]
-          + [Query(func="var", epsilon=0.3)])
-
-    svc_b = AQPService(data, batch_fused=True, **kw)
-    rb = svc_b.answer(qs)
-    assert svc_b.fused_dispatches == 2        # one per func group (avg, var)
-    assert all(r.success for r in rb)
-    # Amortized timing: every lane of a group reports dispatch/k, so the
-    # 2nd..kth queries no longer accumulate the whole group's latency.
-    avg_times = [r.wall_time_s for r in rb[:4]]
-    assert max(avg_times) == min(avg_times) > 0
-
-    svc_l = AQPService(data, batch_fused=False, **kw)
-    rl = svc_l.answer(qs)
-    assert svc_l.fused_dispatches == len(qs)  # one per query
-    for b, l in zip(rb, rl):
-        assert np.array_equal(b.n, l.n)
-        np.testing.assert_allclose(b.error, l.error, rtol=1e-5)
-        np.testing.assert_allclose(b.theta, l.theta, rtol=1e-5)
-    # Identical rows touched either way: the batch changes dispatch count,
-    # never which rows the lanes gather.
-    assert svc_b.rows_touched == svc_l.rows_touched
 
 
 def test_aqp_service_predicate_not_fused():
     """A predicate query with a fusable func must take the host path (the
     fused program has no predicate column): the answer is the predicated
     proportion-style value, not the plain group estimate."""
-    import numpy as np
-
     from repro.aqp.query import Query
     from repro.data import make_grouped
-    from repro.serve.aqp_service import AQPService
+    from repro.serve import AQPSession, Route
 
     data = make_grouped(["normal", "exp"], 60_000, seed=11, biases=[4.0, 2.0])
-    svc = AQPService(data, B=100, n_min=300, n_max=600, max_iters=12,
-                     n_cap=1 << 12, seed=0, reshuffle_every=1000)
+    sess = AQPSession(data, B=100, n_min=300, n_max=600, max_iters=12,
+                      n_cap=1 << 12, seed=0, reshuffle_every=1000)
     q = Query(func="avg", epsilon=0.1, predicate=lambda v: (v[:, 0] > 3.0))
-    r = svc.answer([q])[0]
-    assert svc.fused_dispatches == 0          # host path, not fused
+    r = _answer(sess, [q])[0]
+    assert r.route is Route.HOST
+    assert sess.fused_dispatches == 0         # host path, not fused
     assert r.success
-    truth = svc.engine.exact(q).ravel()       # predicated ground truth
+    truth = sess.engine.exact(q).ravel()      # predicated ground truth
     assert np.linalg.norm(r.theta.ravel() - truth) <= 0.2
     # Sanity: the predicated answer differs from the unpredicated means.
-    plain = svc.engine.exact(Query(func="avg", epsilon=0.1)).ravel()
+    plain = sess.engine.exact(Query(func="avg", epsilon=0.1)).ravel()
     assert np.linalg.norm(plain - truth) > 0.3

@@ -102,15 +102,14 @@ def test_planner_routes_grouped():
     req = Request(query=q)
     assert grouped_fusable(req)
     assert not fusable(req)  # grouped never rides solo lanes
-    kw = dict(pending_fusable=1, pool_busy=False)
-    assert p.route(req, **kw) == Route.POOL
-    assert p.route(req, warm=True, **kw) == Route.WARM
+    assert p.route(req) == Route.POOL
+    assert p.route(req, warm=True) == Route.WARM
     # sharded pools have no grouped block path yet -> host fallback
-    assert Planner(data_shards=2).route(req, **kw) == Route.HOST
+    assert Planner(data_shards=2).route(req) == Route.HOST
     # non-fusable grouped shapes (unsupported metric) also go host-side
     bad = Request(query=Query(func="avg", epsilon=EPS, metric="linf",
                               group_by=True))
-    assert p.route(bad, **kw) == Route.HOST
+    assert p.route(bad) == Route.HOST
 
 
 def test_grouped_cache_signature():

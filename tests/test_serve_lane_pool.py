@@ -1,5 +1,5 @@
 """Phase-D serving (DESIGN.md SS7): resumable fused steps, the heterogeneous
-retire-and-refill lane pool, and the AQPService pool mode.
+retire-and-refill lane pool, and the session's pool route.
 
 The load-bearing invariants:
 
@@ -302,14 +302,16 @@ def test_width_aware_admission(data):
 
 
 # ---------------------------------------------------------------------------
-# Service integration: batch_fused="auto"/"pool"
+# Session integration: the pool route
 # ---------------------------------------------------------------------------
 
 def test_service_pool_mode_mixed_funcs(data):
-    """The service's pool mode serves a mixed-func batch (incl. SUM at
-    population scale) without per-func grouping, with answers matching the
-    per-query loop references."""
-    from repro.serve.aqp_service import AQPService
+    """The session's pool serves a mixed-func batch (incl. SUM at
+    population scale) without per-func grouping, with answers within their
+    bounds of the exact references; a lone fusable query builds the pool
+    too."""
+    from repro.aqp.query import Request
+    from repro.serve import AQPSession, Route
 
     kw = dict(B=100, n_min=300, n_max=600, max_iters=16, n_cap=1 << 13,
               seed=0, reshuffle_every=1000)
@@ -319,26 +321,26 @@ def test_service_pool_mode_mixed_funcs(data):
           Query(func="sum", epsilon=0.2 * float(np.max(data.scale))),
           Query(func="median", epsilon=0.3)]      # host-engine fallback
 
-    svc = AQPService(data, batch_fused="pool", **kw)
-    rs = svc.answer(qs)
+    sess = AQPSession(data, **kw)
+    reqs = [Request(query=q) for q in qs]
+    for r in reqs:
+        sess.submit(r)
+    by_rid = {r.rid: r for r in sess.drain()}
+    rs = [by_rid[r.rid] for r in reqs]
     assert all(r.success for r in rs)
-    assert svc.fused_dispatches >= 1              # pool step syncs counted
-    assert svc._lane_pool is not None
-    assert svc._lane_pool.stats()["retired"] == 4
-    # auto mode picks the pool for multi-query fusable batches.
-    svc_auto = AQPService(data, **kw)
-    assert svc_auto.batch_fused == "auto"
-    rs_auto = svc_auto.answer(qs[:3])
-    assert all(r.success for r in rs_auto)
-    assert svc_auto._lane_pool is not None
-    # ... and the loop for singletons (no pool build).
-    svc_one = AQPService(data, **kw)
-    r1 = svc_one.answer([qs[0]])[0]
-    assert r1.success and svc_one._lane_pool is None
+    assert [r.route for r in rs] == [Route.POOL] * 4 + [Route.HOST]
+    assert sess.fused_dispatches >= 1             # pool step syncs counted
+    assert sess.pool is not None
+    assert sess.pool.stats()["retired"] == 4
+    # ... and a singleton rides the pool as well.
+    one = AQPSession(data, **kw)
+    one.submit(Request(query=qs[0]))
+    (r1,) = one.drain()
+    assert r1.success and r1.route is Route.POOL and one.pool is not None
 
     # Answers agree with the exact references within their bounds.
     for q, r in zip(qs[:4], rs):
-        truth = svc.engine.exact(q).ravel()
+        truth = sess.engine.exact(q).ravel()
         assert np.linalg.norm(r.theta.ravel() - truth) <= 2 * q.epsilon
 
 

@@ -24,7 +24,7 @@ from repro.aqp.query import Query, Request
 from repro.core import estimators
 from repro.core.fused import fused_l2miss
 from repro.data import make_grouped
-from repro.serve import AQPService, AQPSession, LanePool, Planner, Route
+from repro.serve import AQPSession, LanePool, Planner, Route
 from repro.serve.planner import fusable
 
 KW = dict(B=100, n_min=300, n_max=600, max_iters=16, n_cap=1 << 13, seed=0,
@@ -69,7 +69,7 @@ def _pump_done(sess, tickets):
 def test_session_mid_flight_admission_solo_parity(data):
     """Requests admitted via submit() between pump() rounds -- while a
     straggler holds its lane -- answer bit-equal to solo runs."""
-    sess = AQPSession(data, planner=Planner(mode=Route.POOL, pool_lanes=2,
+    sess = AQPSession(data, planner=Planner(pool_lanes=2,
                                             pool_ticks_per_sync=1), **KW)
     keys = jax.random.split(jax.random.PRNGKey(11), 4)
     specs = [("avg", 0.06), ("avg", 0.3), ("var", 0.3), ("std", 0.25)]
@@ -142,7 +142,7 @@ def test_rotation_defers_while_in_flight_and_answers_stay_solo_exact(data):
     under the OLD binding (bit-equal to its solo run), and the first
     request after the idle rotation runs under the NEW one."""
     kw = {**KW, "reshuffle_every": 2}
-    sess = AQPSession(data, planner=Planner(mode=Route.POOL, pool_lanes=2,
+    sess = AQPSession(data, planner=Planner(pool_lanes=2,
                                             pool_ticks_per_sync=1), **kw)
     keys = jax.random.split(jax.random.PRNGKey(23), 4)
 
@@ -220,9 +220,9 @@ def test_pool_request_sample_key_applies_when_idle(data):
 # ---------------------------------------------------------------------------
 
 def test_planner_routes(data):
-    """Auto routing: HOST for non-fusable, LOOP for a cold singleton, POOL
-    for multi-request waves and whenever the pool is already busy."""
-    sess = AQPSession(data, **KW)       # auto planner
+    """Routing: HOST for non-fusable, POOL for every fusable request -- a
+    cold singleton builds the pool, a wave and a busy join ride it."""
+    sess = AQPSession(data, **KW)
     assert not fusable(Request(query=Query(func="median", epsilon=0.3)))
     assert not fusable(Request(query=Query(func="avg", epsilon_rel=0.1)))
     assert not fusable(Request(query=Query(func="avg", epsilon=0.1,
@@ -232,38 +232,37 @@ def test_planner_routes(data):
     t_solo = sess.submit(Request(query=Query(func="avg", epsilon=0.3)))
     r_host, r_solo = _pump_done(sess, [t_host, t_solo])
     assert r_host.route is Route.HOST
-    assert r_solo.route is Route.LOOP
-    assert sess._pool is None           # no pool built for the singleton
+    assert r_solo.route is Route.POOL
+    assert sess._pool is not None       # the singleton built the pool
 
     wave = [sess.submit(Request(query=Query(func="avg", epsilon=0.05 + e)))
             for e in (0.0, 0.2)]
-    sess.pump()                         # wave of 2 -> pool built and busy
-    assert sess._pool is not None and sess._pool.busy_lanes > 0
+    sess.pump()                         # wave of 2 -> the pool is busy
+    assert sess._pool.busy_lanes > 0
     t_join = sess.submit(Request(query=Query(func="var", epsilon=0.3)))
     rs = _pump_done(sess, wave + [t_join])
     assert all(r.route is Route.POOL for r in rs)   # incl. the busy join
 
 
-def test_planner_forced_modes_and_batched_route(data):
-    svc = AQPService(data, batch_fused=True, **KW)
-    qs = [Query(func="avg", epsilon=0.25), Query(func="avg", epsilon=0.3)]
-    rs = svc.answer(qs)
-    assert svc.fused_dispatches == 1                # one func group
-    assert all(r.success for r in rs)
-    # Amortized per-query time: both lanes report dispatch/2.
-    assert rs[0].wall_time_s == rs[1].wall_time_s > 0
-
-    with pytest.raises(ValueError):
-        AQPService(data, batch_fused="nope", **KW)
-    with pytest.raises(TypeError):
-        Planner(mode="pool")                        # Route enum, not string
+def test_sharded_session_serves_a_cold_singleton_in_the_pool(data):
+    """A lone fusable query on a two-shard session runs in the two-shard
+    pool (``mesh=False`` keeps the shard layout on one device) and meets
+    its bound against the exact answer."""
+    sess = AQPSession(data, data_shards=2, mesh=False, **KW)
+    q = Query(func="avg", epsilon=0.2)
+    (r,) = _pump_done(sess, [sess.submit(Request(query=q))])
+    assert r.route is Route.POOL and r.success
+    st = sess.stats()["pool"]
+    assert st["data_shards"] == 2 and st["retired"] == 1
+    truth = sess.engine.exact(q).ravel()
+    assert np.linalg.norm(r.theta.ravel() - truth) <= q.epsilon
 
 
 def test_planner_retunes_cadence_and_rebuilds_at_idle(data):
     """The sliding-window policy: ticks_per_sync follows the epsilon
     spread of the live stream, and a lane-count drift triggers an
     idle-point rebuild after the cooldown."""
-    planner = Planner(mode=Route.POOL, window=6, cooldown=4)
+    planner = Planner(window=6, cooldown=4)
     sess = AQPSession(data, planner=planner, **KW)
 
     # Wave of 6 uniform-epsilon requests: lanes = (6+1)//2 -> 3 -> even 4;
@@ -317,8 +316,7 @@ def test_priority_and_deadline_admission_order(data):
 
 
 def test_session_priority_reaches_pool(data):
-    sess = AQPSession(data, planner=Planner(mode=Route.POOL, pool_lanes=1),
-                      **KW)
+    sess = AQPSession(data, planner=Planner(pool_lanes=1), **KW)
     sess.submit(Request(query=Query(func="avg", epsilon=0.06)))
     sess.pump()                                         # the lane is busy
     t_lo = sess.submit(Request(query=Query(func="avg", epsilon=0.3)))
@@ -331,43 +329,28 @@ def test_session_priority_reaches_pool(data):
 
 
 # ---------------------------------------------------------------------------
-# Accounting: harvest-time rows (the residue fix) + compat wrapper
+# Accounting: harvest-time rows (the residue fix)
 # ---------------------------------------------------------------------------
 
 def test_residue_rows_still_accounted(data):
-    """A pool response that answer() drops as residue (its ticket belongs
-    to an abandoned caller) still lands in rows_touched -- rows are
-    accounted at harvest, not at collection."""
-    svc = AQPService(data, batch_fused="pool", **KW)
+    """A pool response nobody polls (its ticket belongs to an abandoned
+    caller) still lands in rows_touched -- rows are accounted at harvest,
+    not at collection."""
+    sess = AQPSession(data, **KW)
     stray = Request(query=Query(func="avg", epsilon=0.3))
-    svc.session.submit(stray)           # abandoned: never polled
-    out = svc.answer([Query(func="var", epsilon=0.3)])
-    assert len(out) == 1                # the stray is not in answer()'s rows
-    pool = svc._lane_pool
+    sess.submit(stray)                  # abandoned: never polled
+    t = sess.submit(Request(query=Query(func="var", epsilon=0.3)))
+    while sess.in_flight:
+        sess.pump()
+    assert sess.poll(t).success
+    pool = sess.pool
     assert pool.stats()["retired"] == 2
     # Every gathered row -- stray included -- is in the fused accounting.
-    assert svc.session._fused_rows == pool.stats()["rows_gathered"]
+    assert sess._fused_rows == pool.stats()["rows_gathered"]
+    assert sess.rows_touched == sess.store.rows_touched + sess._fused_rows
+    assert [r.rid for r in sess.drain()] == [stray.rid]
     with pytest.raises(KeyError):
-        svc.session.poll(stray.rid)     # popped by drain, dropped by answer
-
-
-def test_answer_compat_wrapper_roundtrip(data):
-    """answer() == submit-all-then-drain: order-preserving, host fallback
-    included, pool accounting visible through the service surface."""
-    svc = AQPService(data, **KW)        # auto
-    qs = [Query(func="avg", epsilon=0.2),
-          Query(func="median", epsilon=0.3),            # host route
-          Query(func="sum", epsilon=0.2 * float(np.max(data.scale))),
-          Query(func="var", epsilon=0.25)]
-    rs = svc.answer(qs)
-    assert [r.qid for r in rs] == [0, 1, 2, 3]
-    assert all(r.success for r in rs)
-    assert svc.session.in_flight == 0
-    assert svc.rows_touched == svc.store.rows_touched + svc.session._fused_rows
-    for q, r in zip(qs, rs):
-        truth = svc.engine.exact(q).ravel()
-        tol = 2 * (q.epsilon if q.epsilon is not None else 0.3)
-        assert np.linalg.norm(r.theta.ravel() - truth) <= tol
+        sess.poll(stray.rid)            # popped by drain
 
 
 # ---------------------------------------------------------------------------
@@ -383,7 +366,7 @@ def test_steady_state_serving_never_recompiles(data, monkeypatch):
     from repro.core.fused import fused_step
 
     monkeypatch.setenv("MISS_SANITIZE", "1")
-    sess = AQPSession(data, planner=Planner(mode=Route.POOL, pool_lanes=2,
+    sess = AQPSession(data, planner=Planner(pool_lanes=2,
                                             pool_ticks_per_sync=1), **KW)
     # Warmup: drive one request per estimator family end to end, so every
     # program a steady stream needs (admission-wave splits, both tier

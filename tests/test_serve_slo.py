@@ -301,13 +301,36 @@ def test_session_shed_and_contract_fields(data):
     assert r2.delivered_epsilon == r2.epsilon == 0.05
 
 
+def test_session_overload_policy_sheds_only_when_armed(data):
+    """The same wave of blown-deadline requests: a ``degrade=True`` session
+    sheds or degrades it, within each answer's delivered bound, while a
+    ``degrade=False`` session runs every request at full fidelity."""
+    kw = {k: v for k, v in SPEC.items() if k not in ("l", "ext_cap")}
+    specs = [("avg", 0.05), ("var", 0.3), ("avg", 0.08)]
+    stats = {}
+    for armed in (True, False):
+        sess = AQPSession(data, degrade=armed, seed=0, **kw)
+        for f, e in specs:
+            sess.submit(Request(Query(f, epsilon=e), deadline_s=1e-9))
+        rs = sess.drain()
+        assert len(rs) == len(specs)
+        for r in rs:
+            if r.shed or r.degraded:
+                assert r.error <= r.delivered_epsilon
+            else:
+                assert r.success and r.delivered_epsilon == r.epsilon
+        stats[armed] = sess.stats()["pool"]
+    assert stats[True]["shed"] + stats[True]["degraded"] > 0
+    assert stats[False]["shed"] == 0 and stats[False]["degraded"] == 0
+
+
 def test_session_degraded_not_cached(data):
     """A degraded answer satisfies only the RELAXED bound, so it must not
     teach the warm cache an entry keyed on the requested epsilon."""
     sess = AQPSession(data, degrade=True, warm_cache=True, seed=0, **{
         k: v for k, v in SPEC.items() if k not in ("l", "ext_cap")})
-    # Build the pool (slo_native: a deadline-carrying fusable request
-    # always rides the pool), then force its cost model to degrade.
+    # Build the pool (every fusable request rides it), then force its cost
+    # model to degrade.
     t0 = sess.submit(Request(Query("avg", epsilon=0.03), deadline_s=60.0))
     sess.drain()
     pool = sess._pool

@@ -48,8 +48,7 @@ def data():
 
 
 def _session(data, **planner):
-    planner = {"mode": Route.POOL, "pool_lanes": 2,
-               "pool_ticks_per_sync": 1, **planner}
+    planner = {"pool_lanes": 2, "pool_ticks_per_sync": 1, **planner}
     return AQPSession(data, planner=Planner(**planner), **KW)
 
 
@@ -129,11 +128,14 @@ def test_pool_built_alone_keeps_its_own_recorder(data):
 
 
 def test_inline_routes_run_under_their_span(data):
-    sess = _session(data, mode=Route.BATCHED)
-    _serve(sess)
+    # A sharded session has no grouped pool block: grouped requests run
+    # on HOST, synchronously inside admission.
+    sess = AQPSession(data, data_shards=2, **KW)
+    rs, _ = _serve(sess, group_by=True)
+    assert all(r.route is Route.HOST for r in rs)
     ph = sess.stats()["phases"]
     assert ph["inline_route"]["calls"] >= 1
-    assert ph["sync"]["calls"] >= 1           # the batched result fetch
+    assert ph["sync"]["calls"] >= 1           # the grouped result fetch
     assert "tick" not in ph
 
 

@@ -385,7 +385,7 @@ def test_session_warm_lane_solo_parity_of_cold_requests(data):
     bit-equal to its solo run -- the warm machinery is invisible until a
     repeat arrives."""
     sess = AQPSession(data, warm_cache=True,
-                      planner=Planner(mode=Route.POOL, pool_lanes=2,
+                      planner=Planner(pool_lanes=2,
                                       pool_ticks_per_sync=1), **KW)
     key = jax.random.PRNGKey(11)
     t = sess.submit(Request(query=Query(func="avg", epsilon=0.2),
